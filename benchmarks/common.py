@@ -24,11 +24,31 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO_ROOT, "src"))
 
 from repro.obs import export as obs_export  # noqa: E402
 from repro.obs import trace  # noqa: E402
 from repro.perf.measure import timed_stream  # noqa: E402,F401 (re-export)
+
+
+# ----------------------------------------------------------- compile cache
+def enable_compile_cache() -> str:
+    """Put JAX's persistent compilation cache in place; returns its dir.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads
+    it itself; nothing else is set here). Otherwise the cache lives at
+    the fixed ``<checkout>/.jax_cache`` — a fixed path, because the path
+    is part of what the cache matches on. Called by entry points before
+    their first compile, never at import, so the tests keep JAX's
+    defaults.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # --------------------------------------------------------------- metrics

@@ -12,7 +12,7 @@ producer, with zero padding — i.e. bottom-right (causal) alignment.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import partial, reduce
 
 import jax.numpy as jnp
 import numpy as np
@@ -22,6 +22,11 @@ from .dsl import Pipeline
 
 
 # ------------------------------------------------------------- window fns
+# A stage function receives {producer key: window}; a window is indexed
+# ``win[..., dy, dx]`` (temporal: ``win[..., dt, dy, dx]``) with
+# non-negative static ints and read for its ``.shape``, nothing else: the
+# reference passes real arrays, the fused kernel a lazy view of its row
+# slabs (kernels.stencil_pipeline._WindowView) that obeys the same rules.
 def _single(wins):
     (v,) = wins.values()
     return v
@@ -63,8 +68,10 @@ def prod_fn(wins):
 
 def nms_fn(wins):
     win = _single(wins)
-    center = win[..., -2, -2] if win.shape[-1] >= 2 else win[..., -1, -1]
-    mx = jnp.max(win, axis=(-2, -1))
+    sh, sw = win.shape[-2:]
+    center = win[..., sh - 2, sw - 2] if sw >= 2 else win[..., sh - 1, sw - 1]
+    mx = reduce(jnp.maximum, (win[..., dy, dx] for dy in range(sh)
+                              for dx in range(sw)))
     return jnp.where(center >= mx, center, 0.0)
 
 

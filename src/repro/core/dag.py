@@ -64,7 +64,10 @@ class Stage:
     """One pipeline stage.
 
     ``fn`` maps a dict {producer_name: window array [..., SH, SW]} to the
-    output pixel value(s) with matching leading batch dims. ``fn=None`` is a
+    output pixel value(s) with matching leading batch dims. It may only
+    index a window as ``win[..., dy, dx]`` with non-negative static ints
+    and read its ``.shape``: the fused kernel passes a lazy view that
+    supports nothing else (see core/algorithms.py). ``fn=None`` is a
     pure relay (identity on a 1x1 window) used by Darkroom linearization.
     """
     name: str

@@ -75,11 +75,8 @@ def pipeline_forward(params_stacked, x_micro, apply_fn, mesh,
         # stage+1 with ppermute.
         stage = jax.lax.axis_index(stage_axis)
         p = jax.tree.map(lambda a: a[0], params)
-        # pvary marks xs device-varying under explicit sharding (jax >=
-        # 0.6); older jax has no varying types, so it's simply absent
-        pvary = getattr(jax.lax, "pvary", None)
-        if pvary is not None:
-            xs = pvary(xs, (stage_axis,))
+        # mark xs device-varying: each stage writes its own outputs
+        xs = jax.lax.pcast(xs, (stage_axis,), to="varying")
         buf = jnp.zeros_like(xs[0])
         outs = jnp.zeros_like(xs)
 

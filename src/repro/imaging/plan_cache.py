@@ -118,7 +118,6 @@ class PlanCache:
     def __init__(self,
                  pipelines: Mapping[str, Callable[[], PipelineDAG]] | None = None,
                  mem: MemConfig | Mapping[str, MemConfig] = DP,
-                 interpret: bool = True,
                  max_plans: int = 256,
                  max_execs: int = 256,
                  tune_options: tuple[MemConfig, ...] = dse.TUNE_OPTIONS,
@@ -146,7 +145,6 @@ class PlanCache:
         self.tune_options = tune_options
         self.tune_max_candidates = tune_max_candidates
         self.default_mem = mem
-        self.interpret = interpret
         self.max_plans = max_plans
         self.max_execs = max_execs
         self.stats = CacheStats(registry=registry)
@@ -285,8 +283,7 @@ class PlanCache:
     def _exec_key(self, name: str, w: int, mkey: tuple, rows_per_step: int,
                   prefetch_depth: int, *legs) -> tuple:
         # leading 5 fields == plan cache_key, so plan eviction can find us
-        return (name, w, mkey, rows_per_step, prefetch_depth) \
-            + legs + (self.interpret,)
+        return (name, w, mkey, rows_per_step, prefetch_depth) + legs
 
     def _store_exec(self, key: tuple, ex) -> None:
         while len(self._execs) >= self.max_execs:
@@ -321,7 +318,7 @@ class PlanCache:
                         prefetch_depth=prefetch_depth, hit=False):
             ex = self._compile(
                 lambda: make_executor(self.dag_for(name), h, w, batch=batch,
-                                      plan=plan, interpret=self.interpret),
+                                      plan=plan),
                 f"exec:{name}:{h}x{w}")
         self.stats.exec_compile_s += time.perf_counter() - t0
         self._store_exec(key, ex)
@@ -360,9 +357,7 @@ class PlanCache:
                         prefetch_depth=prefetch_depth, hit=False):
             ex = self._compile(
                 lambda: make_video_executor(self.dag_for(name), h, w,
-                                            plan=plan,
-                                            interpret=self.interpret,
-                                            chunk=chunk),
+                                            plan=plan, chunk=chunk),
                 f"video_exec:{name}:{h}x{w}")
         self.stats.exec_compile_s += time.perf_counter() - t0
         self._store_exec(key, ex)
@@ -399,6 +394,10 @@ class PlanCache:
         return n
 
     # ----------------------------------------------------------- accounting
+    def executors(self) -> list[StencilExecutor | VideoExecutor]:
+        """The resident executors, least recently used first."""
+        return list(self._execs.values())
+
     def vmem_bytes(self) -> int:
         """High-water VMEM across all resident executors (rings only)."""
         return max((e.vmem_bytes for e in self._execs.values()), default=0)

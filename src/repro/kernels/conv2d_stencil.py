@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .stencil_pipeline import default_interpret
+
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
@@ -29,8 +31,7 @@ def _kernel(img_ref, w_ref, o_ref, *, kh: int, kw: int, tr: int, w: int):
         rows = []
         for t in range(tr):
             r = r0 + t - (kh - 1) + dy
-            row = pl.load(img_ref, (pl.dslice(jnp.maximum(r, 0), 1),
-                                    pl.dslice(0, w)))
+            row = img_ref[pl.ds(jnp.maximum(r, 0), 1), pl.ds(0, w)]
             rows.append(jnp.where(r >= 0, row[0], 0.0))
         block = jnp.stack(rows)                       # (TR, W)
         padded = jnp.pad(block, ((0, 0), (kw - 1, 0)))
@@ -41,8 +42,9 @@ def _kernel(img_ref, w_ref, o_ref, *, kh: int, kw: int, tr: int, w: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_rows"))
 def conv2d(img: jnp.ndarray, weights: jnp.ndarray,
-           tile_rows: int = 8, interpret: bool = True) -> jnp.ndarray:
-    """Causal (bottom-right aligned) conv with zero padding, fp32."""
+           tile_rows: int = 8, interpret: bool | None = None) -> jnp.ndarray:
+    """Causal (bottom-right aligned) conv with zero padding, fp32.
+    ``interpret=None`` runs the interpreter only off the TPU."""
     h, w = img.shape
     kh, kw = weights.shape
     w_pad = _round_up(w, 128)
@@ -58,6 +60,6 @@ def conv2d(img: jnp.ndarray, weights: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((tile_rows, w_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((h_pad, w_pad), jnp.float32),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(img_p, weights.astype(jnp.float32))
     return out[:h, :w]

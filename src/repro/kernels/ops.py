@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU pass interpret=False (the kernels are written against the TPU
-lowering: BlockSpec VMEM tiling, MXU-shaped contractions, (8,128) padding).
+``interpret=None`` (the default) derives the mode from the backend: the
+kernels compile with Mosaic on a TPU and run in the Pallas interpreter
+everywhere else (see ``stencil_pipeline.default_interpret``).
 """
 from __future__ import annotations
 
@@ -15,8 +15,8 @@ from repro.core.codegen import PipelinePlan
 from repro.core.dag import PipelineDAG
 
 from .conv2d_stencil import conv2d
-from .stencil_pipeline import (_resolve_depth, _resolve_rows,
-                               make_pipeline_kernel)
+from .stencil_pipeline import (_resolve_depth, _resolve_interpret,
+                               _resolve_rows, make_pipeline_kernel)
 from .swa_decode import swa_decode
 
 __all__ = ["conv2d", "swa_decode", "fused_pipeline", "make_pipeline_kernel",
@@ -83,20 +83,20 @@ _PIPE_CACHE = _KernelCache()
 
 
 def _pipe_key(dag: PipelineDAG, h: int, w: int, plan: PipelinePlan | None,
-              interpret: bool, rows_per_step: int | None,
+              interpret: bool | None, rows_per_step: int | None,
               prefetch_depth: int | None) -> tuple:
     """Compiled-kernel identity: shape + interpret mode + the resolved
     execution-granularity knobs + the plan's content fingerprint."""
     return (dag.name, h, w,
             plan.fingerprint() if plan is not None else _NO_PLAN,
-            interpret,
+            _resolve_interpret(interpret),
             _resolve_rows(rows_per_step, plan),
             _resolve_depth(prefetch_depth, plan))
 
 
 def fused_pipeline(dag: PipelineDAG, images: dict[str, jnp.ndarray],
                    plan: PipelinePlan | None = None,
-                   interpret: bool = True,
+                   interpret: bool | None = None,
                    rows_per_step: int | None = None,
                    prefetch_depth: int | None = None) -> jnp.ndarray:
     """Run a whole pipeline DAG as one fused line-buffered kernel.
@@ -119,7 +119,7 @@ def pipeline_vmem_bytes(dag: PipelineDAG, h: int, w: int,
                         plan: PipelinePlan | None = None,
                         rows_per_step: int | None = None,
                         prefetch_depth: int | None = None) -> int:
-    key = _pipe_key(dag, h, w, plan, True, rows_per_step, prefetch_depth)
+    key = _pipe_key(dag, h, w, plan, None, rows_per_step, prefetch_depth)
     return _PIPE_CACHE.get_or_build(
         key, lambda: make_pipeline_kernel(dag, h, w, plan=plan,
                                           rows_per_step=rows_per_step,

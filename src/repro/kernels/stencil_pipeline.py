@@ -31,12 +31,13 @@ of the paper's design:
   * SRAM ports    -> no TPU analogue (VMEM is compiler-scheduled); the
     port-contention machinery matters for the ASIC/FPGA backend only.
 
-Ring I/O is vectorized: each edge read is a single contiguous load when
-it provably cannot wrap (SH == 1 — slab start and ring size are both
-multiples of R), and otherwise falls back to a two-segment wrap load
-(both ring segments materialized back-to-back, one dynamic slice picks
-the slab). Slot arithmetic is one positive-mod on the slab origin —
-not one rem per row. Top-of-frame masking is per-row within the slab,
+Ring I/O is vectorized: each edge read is a single contiguous R-aligned
+load when it provably cannot wrap (SH == 1 — slab start and ring size
+are both multiples of R), and otherwise one aligned whole-ring load
+rotated (``pltpu.roll``) so the slab starts at row 0. Slot arithmetic is
+one positive-mod on the slab origin — not one rem per row. Every
+dynamic offset the TPU lowering sees is a multiple of R, which is why it
+takes R % 8 == 0 only. Top-of-frame masking is per-row within the slab,
 so frames batched back-to-back through the same rings never observe
 each other's residue, and the final partial row group of an
 ``h % R != 0`` frame computes into padding rows that are cropped
@@ -45,8 +46,12 @@ upward).
 
 The kernel body is generated from the DAG: stages execute in topological
 order inside the row-group loop, so the whole thing stays a single fused
-Pallas program. Stencil window math is plain VPU work (shift + slice +
-multiply-add over (R, W, SH, SW) window tensors).
+Pallas program. Stencil window math is plain VPU work over 2-D (R, W_pad)
+planes: stage functions read shifted planes of each slab through a lazy
+window view (:class:`_WindowView`), so no tensor of rank > 2 is built —
+the TPU lowering refuses the reshapes such tensors need. Lanes past the
+frame width compute on zero padding and are cropped on return; windows
+only look left, so they never reach a real column.
 """
 from __future__ import annotations
 
@@ -55,6 +60,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.codegen import (PipelinePlan, frame_outputs,
                                 prefetch_ring_bytes, row_group_rings,
@@ -62,11 +68,16 @@ from repro.core.codegen import (PipelinePlan, frame_outputs,
 from repro.obs import trace
 from repro.core.dag import PipelineDAG, window_keys
 
-try:  # pltpu only resolves on TPU builds; interpret mode falls back to ANY
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAVE_PLTPU = False
+
+def default_interpret() -> bool:
+    """Whether Pallas kernels run in the interpreter by default: only
+    off the TPU. The single place the serving path derives it from —
+    every ``interpret=None`` argument resolves here."""
+    return jax.default_backend() != "tpu"
+
+
+def _resolve_interpret(interpret: bool | None) -> bool:
+    return default_interpret() if interpret is None else interpret
 
 
 def _round_up(x: int, m: int) -> int:
@@ -74,55 +85,86 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _stage_read(ring_ref, ring_rows: int, row0: jnp.ndarray, rows_per_step: int,
-                sh: int, w: int) -> jnp.ndarray:
-    """Read the (R + sh - 1, w) slab of rows [row0 - sh + 1, row0 + R - 1]
+                sh: int) -> jnp.ndarray:
+    """Read the (R + sh - 1, W) slab of rows [row0 - sh + 1, row0 + R - 1]
     from a ring buffer, masking rows above the frame top to zero.
 
-    Slot math is one positive-mod on the slab origin (``row0 - sh + 1``
-    can be negative by at most sh - 1 < ring_rows, so adding one period
-    suffices). Row r lives at slot r % ring_rows; the slab is contiguous
-    in ring space except when it crosses the ring end:
+    Row r lives at slot r % ring_rows; the slab is contiguous in ring
+    space except when it crosses the ring end:
 
       * sh == 1 fast path — the slab origin is ``row0``, a multiple of R,
         and ring_rows is a multiple of R, so ``slot + R <= ring_rows``
-        always: one contiguous load, no wrap possible.
-      * wrap fallback — materialize the two ring segments back-to-back
-        (ring, then ring again) and take one dynamic (R + sh - 1)-row
-        slice; index ``slot + j`` of the doubled ring is slot
-        ``(row0 - sh + 1 + j) % ring_rows`` for every slab row j, wrap
-        or not.
+        always: one contiguous R-aligned load, no wrap possible.
+      * otherwise — load the whole ring (offset 0, so sublane-aligned),
+        rotate it so the slab origin's slot lands on row 0, and take the
+        first R + sh - 1 rows: the rotation handles the wrap, and every
+        offset the TPU sees is static or aligned. ``row0 - sh + 1`` can
+        be negative by at most sh - 1 < ring_rows, so one positive-mod
+        on the slab origin gives its slot.
     """
-    s = rows_per_step + sh - 1
-    base = row0 - (sh - 1)
-    slot = jax.lax.rem(base + ring_rows, ring_rows)   # one rem per slab
+    r = rows_per_step
+    s = r + sh - 1
     if sh == 1:
         # base = row0 >= 0: no row can be above the frame top, skip the mask
-        return pl.load(ring_ref, (pl.dslice(slot, s), pl.dslice(0, w)))
-    ring = pl.load(ring_ref, (pl.dslice(0, ring_rows), pl.dslice(0, w)))
-    seg2 = jnp.concatenate([ring, ring], axis=0)
-    slab = jax.lax.dynamic_slice(seg2, (slot, 0), (s, w))
-    live = (base + jnp.arange(s) >= 0)[:, None]       # per-row top mask
-    return jnp.where(live, slab, 0.0)
+        slot = pl.multiple_of(jax.lax.rem(row0, ring_rows), r)
+        return ring_ref[pl.ds(slot, r), :]
+    base = row0 - (sh - 1)
+    slot = jax.lax.rem(base + ring_rows, ring_rows)   # one rem per slab
+    ring = ring_ref[...]
+    slab = pltpu.roll(ring, jax.lax.rem(ring_rows - slot, ring_rows), 0)[:s]
+    live = base + jax.lax.broadcasted_iota(jnp.int32, slab.shape, 0) >= 0
+    return jnp.where(live, slab, 0.0)                  # per-row top mask
 
 
-def _slab_windows(slab: jnp.ndarray, rows_per_step: int, sh: int, sw: int,
-                  w: int) -> jnp.ndarray:
-    """(R + sh - 1, W) slab -> (R, W, sh, sw) bottom-right-aligned windows.
+class _WindowView:
+    """Bottom-right-aligned stencil windows over row slabs, built lazily.
 
-    Pure shift-and-slice: sh + sw static slices of the slab, no per-row
-    python loop. Window (i, x, dy, dx) is pixel (row0 + i - sh + 1 + dy,
-    x - sw + 1 + dx) — the same causal alignment as the reference
-    executor's ``_windows``.
+    Stands in for the reference executor's ``(R, W, sh, sw)`` (spatial)
+    or ``(R, W, st, sh, sw)`` (temporal) window arrays: ``view[..., dy,
+    dx]`` (``view[..., dt, dy, dx]``) is the (R, W) plane of pixel
+    (row0 + i - sh + 1 + dy, x - sw + 1 + dx) of temporal tap dt — the
+    same causal alignment as ``algorithms._windows`` — and ``.shape``
+    reports the array it stands for. Nothing of rank > 2 is built, which
+    is what the TPU lowering accepts: a column shift is the slab padded
+    with zero columns on the left (the frame's left padding) and sliced
+    back to its width, a row shift a static sublane slice. Shifted slabs
+    are memoized per (dt, dx). Stage functions index it with
+    non-negative static ints only.
     """
-    padded = jnp.pad(slab, ((0, 0), (sw - 1, 0)))
-    cols = jnp.stack([padded[:, dx:dx + w] for dx in range(sw)],
-                     axis=-1)                             # (S, W, sw)
-    return jnp.stack([cols[dy:dy + rows_per_step] for dy in range(sh)],
-                     axis=2)                              # (R, W, sh, sw)
+
+    def __init__(self, slabs: list, rows_per_step: int, sh: int, sw: int,
+                 temporal: bool):
+        self._slabs = slabs               # one (R + sh - 1, W) slab per dt
+        self._r, self._sw = rows_per_step, sw
+        self._temporal = temporal
+        self._cols: dict[tuple[int, int], jnp.ndarray] = {}
+        w = slabs[0].shape[1]
+        self.shape = ((rows_per_step, w)
+                      + ((len(slabs),) if temporal else ()) + (sh, sw))
+
+    def __getitem__(self, key) -> jnp.ndarray:
+        n = 3 if self._temporal else 2
+        if not (isinstance(key, tuple) and len(key) == n + 1
+                and key[0] is Ellipsis):
+            raise TypeError(f"window view takes [..., {'dt, ' * (n == 3)}"
+                            f"dy, dx], got {key!r}")
+        idx = key[1:]
+        for i, ext in zip(idx, self.shape[-n:]):
+            if not isinstance(i, int) or not 0 <= i < ext:
+                raise IndexError(f"window index {idx} outside "
+                                 f"{self.shape[-n:]}")
+        dt, dy, dx = idx if self._temporal else (0, *idx)
+        cols = self._cols.get((dt, dx))
+        if cols is None:
+            slab, k = self._slabs[dt], self._sw - 1 - dx
+            if k:
+                slab = jnp.pad(slab, ((0, 0), (k, 0)))[:, :slab.shape[1]]
+            cols = self._cols[(dt, dx)] = slab
+        return cols[dy:dy + self._r]
 
 
 def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
-                         plan: PipelinePlan | None, interpret: bool,
+                         plan: PipelinePlan | None, interpret: bool | None,
                          batch: int | None, rows_per_step: int = 1,
                          prefetch_depth: int = 1):
     """Shared kernel builder for the single-frame and batched executors.
@@ -140,15 +182,14 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
         stream through BlockSpec grid slices, the Pallas pipeline
         double-buffers implicitly. Bit-for-bit the historical behavior.
       * **2 / 4 (multi-buffered)** — inputs and outputs become whole
-        ``pltpu.ANY`` (HBM) operands and every feed/output owns a
+        ``pl.ANY`` (HBM) operands and every feed/output owns a
         (depth, R, W_pad) VMEM prefetch/staging ring driven by
         ``pltpu.make_async_copy``: step t computes from ring slot
         ``t % depth`` while the DMAs for steps t+1..t+depth-1 are in
         flight, and output slabs drain asynchronously behind compute —
         the paper's push-memory overlap, depth slabs deep. Grid steps
         are linearized ``t = b * n_groups + g`` so one ring and one
-        semaphore array serve the whole batch. Falls back to the
-        synchronous path when ``pltpu`` is unavailable.
+        semaphore array serve the whole batch.
 
     Temporal pipelines add two kinds of operands around that same loop:
 
@@ -172,14 +213,18 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
     (out, {producer: frame})``. ``images`` must carry one entry per
     input stage plus one per tap (keyed ``codegen.tap_name(p, j)``).
     """
+    interpret = _resolve_interpret(interpret)
     r = rows_per_step
     if r < 1:
         raise ValueError(f"rows_per_step must be >= 1, got {r}")
+    if not interpret and r % 8:
+        # the TPU lowering moves row groups as whole (8, 128) float32
+        # tiles: a BlockSpec or DMA slab of R % 8 != 0 rows is refused
+        raise ValueError(f"rows_per_step={r} does not lower for the TPU: "
+                         f"it must be a multiple of 8")
     depth = prefetch_depth
     if depth < 1:
         raise ValueError(f"prefetch_depth must be >= 1, got {depth}")
-    if depth > 1 and not _HAVE_PLTPU:  # pragma: no cover
-        depth = 1   # no async-copy primitives: synchronous fallback
     n_groups = -(-h // r)
     h_pad = n_groups * r
     rings = row_group_rings(dag, plan.alloc.buffers if plan else None, r)
@@ -217,27 +262,27 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
     def stage_pass(read_feed, store_out, store_frame, ring_refs, row0):
         """The topological stage loop, shared by both I/O disciplines.
 
-        ``read_feed(name)`` yields a feed's (R, w) block for this step;
-        ``store_out(val)`` / ``store_frame(p, val)`` emit the pipeline
+        ``read_feed(name)`` yields a feed's (R, W_pad) block for this
+        step; ``store_out(val)`` / ``store_frame(p, val)`` emit the pipeline
         output and the internal temporal producers' frames. Everything
         between — tap-ring staging, slab reads, window assembly, ring
         writes — is identical whether blocks arrive via BlockSpec or
         through DMA prefetch rings.
         """
+        def store_ring(name, val):
+            # rr % R == 0 and row0 % R == 0: the write never wraps
+            rr = ring_shapes[name][0]
+            slot = pl.multiple_of(jax.lax.rem(row0, rr), r)
+            ring_refs[name][pl.ds(slot, r), :] = val
+
+        def slab(src: str, sh: int) -> jnp.ndarray:
+            return _stage_read(ring_refs[src], ring_shapes[src][0], row0,
+                               r, sh)
+
         # stream the history taps into their rings first: consumers later
         # in this same grid step read their slabs like any producer ring
         for (p, j) in taps:
-            name = tap_name(p, j)
-            val = read_feed(name)
-            rr = ring_shapes[name][0]
-            pl.store(ring_refs[name],
-                     (pl.dslice(jax.lax.rem(row0, rr), r),
-                      pl.dslice(0, w)), val)
-
-        def slab_windows(src: str, e) -> jnp.ndarray:
-            rr = ring_shapes[src][0]
-            slab = _stage_read(ring_refs[src], rr, row0, r, e.sh, w)
-            return _slab_windows(slab, r, e.sh, e.sw, w)
+            store_ring(tap_name(p, j), read_feed(tap_name(p, j)))
 
         for name in dag.topo_order:
             st = dag.stages[name]
@@ -246,31 +291,19 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
             if st.is_input:
                 val = read_feed(name)
             elif st.fn is None:  # relay: identity on the producer's R rows
-                e = dag.in_edges(name)[0]
-                rr = ring_shapes[e.producer][0]
-                val = _stage_read(ring_refs[e.producer], rr, row0, r, 1, w)
+                val = slab(dag.in_edges(name)[0].producer, 1)
             else:
                 ins = dag.in_edges(name)
-                wins = {}
-                for key, e in zip(window_keys(ins), ins):
-                    if e.st == 1:
-                        wins[key] = slab_windows(e.producer, e)
-                    else:
-                        # (R, W, st, sh, sw): tap st-1-dt feeds temporal
-                        # index dt, so index st-1 is the current frame —
-                        # causal alignment, like the spatial axes
-                        wins[key] = jnp.stack(
-                            [slab_windows(
-                                e.producer if j == 0
-                                else tap_name(e.producer, j), e)
-                             for j in range(e.st - 1, -1, -1)], axis=2)
-                val = st.fn(wins)  # (R, W)
+                # temporal index dt is tap st-1-dt, so index st-1 is the
+                # current frame — causal alignment, like the spatial axes
+                wins = {key: _WindowView(
+                    [slab(e.producer if j == 0 else tap_name(e.producer, j),
+                          e.sh) for j in range(e.st - 1, -1, -1)],
+                    r, e.sh, e.sw, temporal=e.st > 1)
+                    for key, e in zip(window_keys(ins), ins)}
+                val = st.fn(wins)  # (R, W_pad)
             if name in ring_refs:
-                rr = ring_shapes[name][0]
-                # rr % R == 0 and row0 % R == 0: the write never wraps
-                slot = jax.lax.rem(row0, rr)
-                pl.store(ring_refs[name],
-                         (pl.dslice(slot, r), pl.dslice(0, w)), val)
+                store_ring(name, val)
             if name in frame_outs:
                 store_frame(name, val)
             if name == final:
@@ -294,13 +327,13 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
             row0 = pl.program_id(group_axis) * r   # first row of this group
 
             def read_feed(name):
-                return in_refs[name][lead + (slice(None), slice(0, w))]
+                return in_refs[name][lead]
 
             def store_out(val):
-                out_ref[lead + (slice(None), slice(0, w))] = val
+                out_ref[lead] = val
 
             def store_frame(p, val):
-                frame_refs[p][lead + (slice(None), slice(0, w))] = val
+                frame_refs[p][lead] = val
 
             stage_pass(read_feed, store_out, store_frame, ring_refs, row0)
 
@@ -310,12 +343,8 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
             blk, index_map = (r, w_pad), (lambda g: (g, 0))
         in_specs = [pl.BlockSpec(blk, index_map) for _ in feeds]
         out_specs = [pl.BlockSpec(blk, index_map)] * n_outs
-        if _HAVE_PLTPU:
-            scratch = [pltpu.VMEM(ring_shapes[p], jnp.float32)
-                       for p in ring_owners]
-        else:  # pragma: no cover
-            scratch = [pl.MemorySpace.ANY(ring_shapes[p], jnp.float32)
-                       for p in ring_owners]
+        scratch = [pltpu.VMEM(ring_shapes[p], jnp.float32)
+                   for p in ring_owners]
     else:
         outs = ["__out__"] + frame_outs
         total = (batch if batched else 1) * n_groups
@@ -342,9 +371,9 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
                 if batched:
                     bb = u // n_groups
                     gg = u - bb * n_groups
-                    src = hbm_in[name].at[bb, pl.dslice(gg * r, r), :]
+                    src = hbm_in[name].at[bb, pl.ds(gg * r, r), :]
                 else:
-                    src = hbm_in[name].at[pl.dslice(u * r, r), :]
+                    src = hbm_in[name].at[pl.ds(u * r, r), :]
                 return pltpu.make_async_copy(src, pf_in[name].at[s],
                                              in_sems[name].at[s])
 
@@ -353,9 +382,9 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
                 if batched:
                     bb = u // n_groups
                     gg = u - bb * n_groups
-                    dst = hbm_out[o].at[bb, pl.dslice(gg * r, r), :]
+                    dst = hbm_out[o].at[bb, pl.ds(gg * r, r), :]
                 else:
-                    dst = hbm_out[o].at[pl.dslice(u * r, r), :]
+                    dst = hbm_out[o].at[pl.ds(u * r, r), :]
                 return pltpu.make_async_copy(pf_out[o].at[s], dst,
                                              out_sems[o].at[s])
 
@@ -379,16 +408,13 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
                     out_dma(o, t - depth, slot).wait()
 
             def read_feed(name):
-                return pl.load(pf_in[name],
-                               (slot, pl.dslice(0, r), pl.dslice(0, w)))
+                return pf_in[name][slot]
 
             def store_out(val):
-                pl.store(pf_out["__out__"],
-                         (slot, pl.dslice(0, r), pl.dslice(0, w)), val)
+                pf_out["__out__"][slot] = val
 
             def store_frame(p, val):
-                pl.store(pf_out[p],
-                         (slot, pl.dslice(0, r), pl.dslice(0, w)), val)
+                pf_out[p][slot] = val
 
             stage_pass(read_feed, store_out, store_frame, ring_refs, row0)
 
@@ -410,7 +436,7 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
                     for o in outs:
                         out_dma(o, u, jax.lax.rem(u, depth)).wait()
 
-        any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
         in_specs = [any_spec for _ in feeds]
         out_specs = [any_spec] * n_outs
         scratch = (
@@ -427,6 +453,10 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        # the VMEM rings carry rows from one grid step to the next and
+        # from one batch frame to the next: no axis may be split
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
     )
 
@@ -465,7 +495,7 @@ def _resolve_depth(prefetch_depth: int | None,
 
 def make_pipeline_kernel(dag: PipelineDAG, h: int, w: int,
                          plan: PipelinePlan | None = None,
-                         interpret: bool = True,
+                         interpret: bool | None = None,
                          rows_per_step: int | None = None,
                          prefetch_depth: int | None = None):
     """Build a jit-compiled fused executor for ``dag`` on (h, w) images.
@@ -484,7 +514,7 @@ def make_pipeline_kernel(dag: PipelineDAG, h: int, w: int,
 
 def make_batched_pipeline_kernel(dag: PipelineDAG, batch: int, h: int, w: int,
                                  plan: PipelinePlan | None = None,
-                                 interpret: bool = True,
+                                 interpret: bool | None = None,
                                  rows_per_step: int | None = None,
                                  prefetch_depth: int | None = None):
     """Batched variant: one fused Pallas program over a frame batch.
@@ -550,7 +580,7 @@ class StencilExecutor:
 def make_executor(dag: PipelineDAG, h: int, w: int,
                   batch: int | None = None,
                   plan: PipelinePlan | None = None,
-                  interpret: bool = True,
+                  interpret: bool | None = None,
                   rows_per_step: int | None = None,
                   prefetch_depth: int | None = None) -> StencilExecutor:
     """Executor factory: DAG + shape (+ optional plan) -> StencilExecutor."""
@@ -559,6 +589,7 @@ def make_executor(dag: PipelineDAG, h: int, w: int,
                          f"make_video_executor")
     r = _resolve_rows(rows_per_step, plan)
     d = _resolve_depth(prefetch_depth, plan)
+    interpret = _resolve_interpret(interpret)
     fn, vmem = _build_pipeline_call(dag, h, w, plan, interpret, batch,
                                     rows_per_step=r, prefetch_depth=d)
     return StencilExecutor(dag=dag, h=h, w=w, batch=batch, rows_per_step=r,
@@ -630,7 +661,7 @@ class VideoExecutor:
 
 def make_video_executor(dag: PipelineDAG, h: int, w: int,
                         plan: PipelinePlan | None = None,
-                        interpret: bool = True,
+                        interpret: bool | None = None,
                         rows_per_step: int | None = None,
                         chunk: int | None = None,
                         prefetch_depth: int | None = None) -> VideoExecutor:
@@ -644,6 +675,7 @@ def make_video_executor(dag: PipelineDAG, h: int, w: int,
     """
     r = _resolve_rows(rows_per_step, plan)
     d = _resolve_depth(prefetch_depth, plan)
+    interpret = _resolve_interpret(interpret)
     depths = dag.temporal_depths()
     inputs = set(dag.input_stages())
     internal = sorted(p for p in depths if p not in inputs)

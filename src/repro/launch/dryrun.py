@@ -28,7 +28,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as shd
-from repro.launch.mesh import TPU_V5E, make_production_mesh, mesh_scope
+from repro.launch.mesh import TPU_V5E, make_production_mesh
 from repro.launch.shapes import (SHAPES, cell_status, decode_input_specs,
                                  prefill_input_specs, train_input_specs)
 from repro.models import build_model, get_config
@@ -160,7 +160,7 @@ def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str,
     else:
         mctx = contextlib.nullcontext()
     t0 = time.time()
-    with mesh_scope(mesh), ctx, mctx:
+    with jax.set_mesh(mesh), ctx, mctx:
         return _lower_cell_inner(res, model, cfg, sh, kind, mesh, mesh_name,
                                  t0, verbose)
 

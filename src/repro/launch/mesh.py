@@ -8,39 +8,22 @@ from __future__ import annotations
 import jax
 
 
-def _auto(n):
-    """Auto axis types on jax >= 0.5 (where explicit sharding landed);
-    None — meaning "omit the kwarg" — on older jax, whose meshes are
-    implicitly Auto."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return None if at is None else (at.Auto,) * n
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across the AxisType API drift (kwarg added ~0.5)."""
-    types = _auto(len(axes))
-    if types is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=types)
-
-
-def mesh_scope(mesh):
-    """Context manager activating ``mesh``: jax.set_mesh on new jax, the
-    Mesh object's own context manager (the old global resource-env entry
-    point) before set_mesh existed."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def make_mesh(shape, axes):
+    """jax.make_mesh with every axis Auto: shardings follow the specs
+    the caller attaches. Activate it with ``jax.set_mesh``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 4):
     """Small mesh for CPU-host tests (needs XLA host platform devices)."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # Hardware constants for the roofline analysis (assignment-provided).

@@ -17,8 +17,7 @@ by :mod:`repro.perf.attribution`:
     once*, not x trip count, and the Pallas kernels run in interpret
     mode on CPU — the HLO the analysis sees is the interpreter's
     program, so treat flops/bytes as a consistent *relative* signal
-    between pipelines, not device truth. Pre-0.5 jax returns one dict
-    per program; both spellings are normalized here.
+    between pipelines, not device truth.
   * **trace breakdown** (:func:`step_breakdown`) — queue-wait vs
     assemble vs execute *self*-time per pipeline, aggregated from the
     obs plane's ``engine.step`` spans (reusing the flame summary's
@@ -116,12 +115,6 @@ def classify(flops: float, bytes_moved: float, peaks: Peaks) -> dict:
 
 
 # ------------------------------------------------------------- cost side
-def _normalize_cost(ca) -> dict:
-    if isinstance(ca, (list, tuple)):    # pre-0.5 jax: dict per program
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def _example_args(ex) -> tuple:
     """Zero-filled example arguments matching the executor's signature."""
     shape = (ex.h, ex.w)
@@ -149,7 +142,7 @@ def executor_cost(ex) -> dict | None:
     try:
         args = _example_args(ex)
         compiled = ex._fn.lower(*args).compile()
-        ca = _normalize_cost(compiled.cost_analysis())
+        ca = compiled.cost_analysis() or {}
         out = {"flops": float(ca.get("flops", 0.0)),
                "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
                "arg_bytes": 0, "out_bytes": 0, "temp_bytes": 0}

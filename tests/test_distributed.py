@@ -2,9 +2,8 @@
 
 Multi-device cases run in a subprocess (jax pins the device count at
 first init, and the main test process must stay single-device for the
-other suites). Mesh construction and activation go through the
-launch.mesh compat helpers (compat_make_mesh / mesh_scope) so the suite
-runs on both pre- and post-AxisType jax.
+other suites). Meshes come from ``launch.mesh.make_mesh`` and are
+activated with ``jax.set_mesh``.
 """
 import json
 import os
@@ -98,8 +97,7 @@ def test_pjit_train_step_runs_on_host_mesh():
     step = jax.jit(make_train_step(m, opt),
                    in_shardings=(named(sspec), named(bspec)),
                    out_shardings=(named(sspec), None))
-    from repro.launch.mesh import mesh_scope
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         state2, metrics = step(state, batch)
         state3, metrics2 = step(state2, batch)
     assert np.isfinite(float(metrics2["loss"]))
@@ -113,8 +111,8 @@ def test_pipeline_forward_multidevice():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_forward
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("stage",))
     n_stages, n_micro, mb, d = 4, 6, 2, 8
     ks = jax.random.split(jax.random.PRNGKey(0), n_stages)
     w = jnp.stack([jax.random.normal(k, (d, d)) * 0.3 for k in ks])
@@ -141,12 +139,12 @@ def test_dryrun_single_cell_small():
     import jax, dataclasses
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import compat_make_mesh, mesh_scope
+    from repro.launch.mesh import make_mesh
     from repro.models import build_model, get_config
     from repro.distributed import sharding as shd
     from repro.train import OptConfig, make_train_step
     from repro.train.optimizer import init_opt_state
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = dataclasses.replace(get_config("gemma3-1b"), n_layers=6,
         d_model=64, n_heads=4, n_kv_heads=1, head_dim=16, d_ff=128,
         vocab=256, window=8)
@@ -163,13 +161,11 @@ def test_dryrun_single_cell_small():
     named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                    is_leaf=lambda x: isinstance(x, P))
     step = make_train_step(m, opt)
-    with mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         jf = jax.jit(step, in_shardings=(named(sspec), named(bspec)),
                      out_shardings=(named(sspec), None))
         compiled = jf.lower(state_shape, batch).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # pre-0.5 jax returns [dict]
-        ca = ca[0]
     assert ca.get("flops", 0) > 0
     print("OK flops", ca["flops"])
     """

@@ -100,3 +100,60 @@ def test_pipeline_vmem_accounting():
     # rings padded to (8k, 128) fp32 tiles
     assert vb % (8 * 128 * 4) == 0
     assert vb > 0
+
+
+def test_interpret_derived_from_backend():
+    """Off the TPU the serving path runs the Pallas interpreter without
+    anyone asking for it; on a TPU the same defaults compile Mosaic."""
+    import jax
+    from repro.imaging import PlanCache
+    from repro.kernels.stencil_pipeline import default_interpret
+    assert default_interpret() == (jax.default_backend() != "tpu")
+    cache = PlanCache()
+    ex = cache.executor_for("canny-m", 8, 16, batch=2, rows_per_step=8)
+    vex = cache.video_executor_for("tdenoise-t", 8, 16, rows_per_step=8)
+    assert ex.interpret is vex.interpret is default_interpret()
+    assert cache.executors() == [ex, vex]
+
+
+def _view_and_array(rows, sh, sw, w=20, st=None):
+    """The same windows twice: as the kernel's lazy view over row slabs
+    and as the reference's real array, for ``rows`` output rows."""
+    from repro.kernels.stencil_pipeline import _WindowView
+    slabs = [jnp.asarray(RNG.rand(rows + sh - 1, w).astype(np.float32))
+             for _ in range(st or 1)]
+    arrs = [algorithms._windows(s, sh, sw)[sh - 1:] for s in slabs]
+    arr = arrs[0] if st is None else jnp.stack(arrs, axis=2)
+    return _WindowView(slabs, rows, sh, sw, temporal=st is not None), arr
+
+
+@pytest.mark.parametrize("sh,sw", [(3, 3), (5, 3), (3, 1), (1, 1)])
+def test_window_view_matches_array_nms(sh, sw):
+    view, arr = _view_and_array(8, sh, sw)
+    assert view.shape == arr.shape
+    np.testing.assert_array_equal(
+        np.asarray(algorithms.nms_fn({"x": view})),
+        np.asarray(algorithms.nms_fn({"x": arr})))
+
+
+def test_window_view_matches_array_xcorr_and_temporal():
+    tall_v, tall_a = _view_and_array(8, 18, 1)
+    ctr_v, ctr_a = _view_and_array(8, 1, 1)
+    np.testing.assert_array_equal(
+        np.asarray(algorithms.xcorr_fn({"a": tall_v, "b": ctr_v})),
+        np.asarray(algorithms.xcorr_fn({"a": tall_a, "b": ctr_a})))
+    tv, ta = _view_and_array(8, 3, 3, st=3)
+    assert tv.shape == ta.shape
+    fn = algorithms.stmean_fn(3, 3, 3)
+    np.testing.assert_array_equal(np.asarray(fn({"x": tv})),
+                                  np.asarray(fn({"x": ta})))
+
+
+def test_window_view_takes_only_static_in_range_indices():
+    view, _ = _view_and_array(8, 3, 3)
+    with pytest.raises(IndexError):
+        view[..., -1, 0]
+    with pytest.raises(IndexError):
+        view[..., 0, 3]
+    with pytest.raises(TypeError):
+        view[0]
