@@ -284,16 +284,26 @@ class FrameEngine:
         ex = self.cache.executor_for(name, h, w, batch=self.max_batch,
                                      rows_per_step=rps, tune=tune,
                                      prefetch_depth=self.prefetch_depth)
-        with trace.span("engine.assemble", pipeline=name):
-            inputs = {n: jnp.stack(pad_batch(
-                [jnp.asarray(r.frames[n], jnp.float32) for r in reqs],
-                self.max_batch,
-                lambda: jnp.zeros((h, w), jnp.float32)))
-                for n in self.cache.dag_for(name).input_stages()}
+        feeds = self.cache.dag_for(name).input_stages()
+        with trace.span("engine.assemble", pipeline=name, xla=True):
+            with trace.span("engine.h2d", pipeline=name, xla=True,
+                            frames=len(reqs),
+                            bytes=len(reqs) * len(feeds) * h * w * 4):
+                copies = {n: [jnp.asarray(r.frames[n], jnp.float32)
+                              for r in reqs] for n in feeds}
+            with trace.span("engine.stack", pipeline=name, xla=True):
+                inputs = {n: jnp.stack(pad_batch(
+                    c, self.max_batch,
+                    lambda: jnp.zeros((h, w), jnp.float32)))
+                    for n, c in copies.items()}
+            del copies      # the per-frame copies die once stacked
         with trace.span("engine.execute", pipeline=name, xla=True):
             batch_out = ex(inputs)
             batch_out.block_until_ready()
-        return [batch_out[i] for i in range(len(reqs))], ex.vmem_bytes
+        with trace.span("engine.deliver", pipeline=name, xla=True,
+                        frames=len(reqs)):
+            outs = [batch_out[i] for i in range(len(reqs))]
+        return outs, ex.vmem_bytes
 
     def _run_reference(self, name: str,
                        reqs: list[FrameRequest]) -> tuple[list, int]:
@@ -366,9 +376,9 @@ class FrameEngine:
         # height on the tiled path, by the frame height otherwise
         rps = rows_per_step_for_tile(min(th, h) if tiled else h,
                                      self.rows_per_step)
-        with trace.span("engine.step", engine="frame", pipeline=name,
-                        n_frames=len(reqs), tiled=tiled, rows_per_step=rps,
-                        queue_wait_s=queue_wait) as sp:
+        with trace.span("engine.step", xla=True, engine="frame",
+                        pipeline=name, n_frames=len(reqs), tiled=tiled,
+                        rows_per_step=rps, queue_wait_s=queue_wait) as sp:
             t0 = time.perf_counter()
             try:
                 outs, vmem, rung = self._execute(name, reqs, h, w,

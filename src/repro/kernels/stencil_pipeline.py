@@ -56,6 +56,7 @@ only look left, so they never reach a real column.
 from __future__ import annotations
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +79,18 @@ def default_interpret() -> bool:
 
 def _resolve_interpret(interpret: bool | None) -> bool:
     return default_interpret() if interpret is None else interpret
+
+
+def _device_name(prefix: str, dag: PipelineDAG) -> str:
+    """``<prefix>_<pipeline>``, the pipeline's name made an identifier:
+    the name a kernel or program carries in the device trace."""
+    return prefix + "_" + re.sub(r"\W", "_", dag.name)
+
+
+def _named_jit(fn, name: str):
+    """``jax.jit(fn)`` whose program is ``jit_<name>`` in the trace."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -458,9 +471,9 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
+        name=_device_name("imagen_stencil", dag),
     )
 
-    @jax.jit
     def fn(images: dict[str, jnp.ndarray]):
         # pad rows to the row-group boundary and cols to the lane tile;
         # padding rows compute garbage that is cropped here and, being
@@ -476,7 +489,8 @@ def _build_pipeline_call(dag: PipelineDAG, h: int, w: int,
         return out, {p: outs[1 + i][..., :h, :w]
                      for i, p in enumerate(frame_outs)}
 
-    return fn, vmem_bytes
+    program = "imagen_frame_batch" if batched else "imagen_frame"
+    return _named_jit(fn, _device_name(program, dag)), vmem_bytes
 
 
 def _resolve_rows(rows_per_step: int | None,
@@ -683,7 +697,6 @@ def make_video_executor(dag: PipelineDAG, h: int, w: int,
                                     rows_per_step=r, prefetch_depth=d)
     taps = temporal_taps(dag)
 
-    @jax.jit
     def step(images, state):
         feed = {n: jnp.asarray(images[n], jnp.float32)
                 for n in dag.input_stages()}
@@ -717,4 +730,5 @@ def make_video_executor(dag: PipelineDAG, h: int, w: int,
                          frame_state_bytes=sum((d - 1) * h * w * 4
                                                for d in depths.values()),
                          interpret=interpret, depths=dict(depths), plan=plan,
-                         _fn=step)
+                         _fn=_named_jit(step, _device_name(
+                             "imagen_video_step", dag)))

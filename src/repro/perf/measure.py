@@ -244,25 +244,28 @@ def measure_executor(ex, frames: int, rng: np.random.RandomState,
 
 # ------------------------------------------------------------ trace side
 def step_breakdown(trace_data: dict, pipeline: str) -> dict | None:
-    """Queue-wait / assemble / execute split for one pipeline's steps.
+    """Queue-wait / assemble / execute / deliver split for one
+    pipeline's steps.
 
     Reads a Chrome-trace dict (``export.to_chrome_trace`` output or a
     ``--trace`` file) and aggregates, over every ``engine.step`` span
     whose ``pipeline`` attr matches: the summed queue wait (span attr,
     clocked by the engine), the total durations of the nested
-    ``engine.assemble`` / ``engine.execute`` children, and the step
-    *self* time left over (batching, delivery, metrics — computed with
-    the flame summary's containment arithmetic). Returns seconds, or
-    None when the trace holds no matching step spans; the returned
-    parts feed :func:`repro.perf.model.exact_fractions` so the report's
-    time split provably partitions the step total.
+    ``engine.assemble`` / ``engine.execute`` / ``engine.deliver``
+    children, and the step *self* time left over (batching, results,
+    metrics — computed with the flame summary's containment
+    arithmetic). Returns seconds, or None when the trace holds no
+    matching step spans; the returned parts feed
+    :func:`repro.perf.model.exact_fractions` so the report's time split
+    provably partitions the step total.
     """
     spans = _span_rows(trace_data)
     if not spans:
         return None
     self_us = _self_times_us(spans)
     step_us = queue_s = 0.0
-    parts_us = {"assemble": 0.0, "execute": 0.0, "step_self": 0.0}
+    parts_us = {"assemble": 0.0, "execute": 0.0, "deliver": 0.0,
+                "step_self": 0.0}
     n_steps = 0
     for e, s in zip(spans, self_us):
         if (e.get("args") or {}).get("pipeline") != pipeline:
@@ -276,6 +279,8 @@ def step_breakdown(trace_data: dict, pipeline: str) -> dict | None:
             parts_us["assemble"] += float(e["dur"])
         elif e["name"] == "engine.execute":
             parts_us["execute"] += float(e["dur"])
+        elif e["name"] == "engine.deliver":
+            parts_us["deliver"] += float(e["dur"])
     if n_steps == 0:
         return None
     return {
@@ -284,5 +289,6 @@ def step_breakdown(trace_data: dict, pipeline: str) -> dict | None:
         "queue_wait_s": queue_s,
         "assemble_s": parts_us["assemble"] / 1e6,
         "execute_s": parts_us["execute"] / 1e6,
+        "deliver_s": parts_us["deliver"] / 1e6,
         "step_self_s": parts_us["step_self"] / 1e6,
     }

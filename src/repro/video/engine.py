@@ -346,15 +346,21 @@ class VideoEngine:
                                            rows_per_step=rps,
                                            tune=tune,
                                            prefetch_depth=self.prefetch_depth)
-        with trace.span("engine.assemble", pipeline=s.pipeline):
-            ins = {name: jnp.stack(
-                [jnp.asarray(f.frames[name], jnp.float32) for f in frames])
-                for name in s.inputs}
+        with trace.span("engine.assemble", pipeline=s.pipeline, xla=True):
+            with trace.span("engine.h2d", pipeline=s.pipeline, xla=True,
+                            frames=n, bytes=n * len(s.inputs) * s.h * s.w * 4):
+                copies = {name: [jnp.asarray(f.frames[name], jnp.float32)
+                                 for f in frames] for name in s.inputs}
+            with trace.span("engine.stack", pipeline=s.pipeline, xla=True):
+                ins = {name: jnp.stack(c) for name, c in copies.items()}
+            del copies      # the per-frame copies die once stacked
         with trace.span("engine.execute", pipeline=s.pipeline, xla=True):
             out, new_state = ex(ins, s.state)
             out.block_until_ready()
-        return ([out[i] for i in range(n)], new_state,
-                ex.vmem_bytes + ex.frame_state_bytes)
+        with trace.span("engine.deliver", pipeline=s.pipeline, xla=True,
+                        frames=n):
+            outs = [out[i] for i in range(n)]
+        return outs, new_state, ex.vmem_bytes + ex.frame_state_bytes
 
     def _run_frame(self, s: VideoSession, f: VideoFrame,
                    rps: int, tune: bool):
@@ -363,8 +369,12 @@ class VideoEngine:
                                            rows_per_step=rps,
                                            tune=tune,
                                            prefetch_depth=self.prefetch_depth)
+        with trace.span("engine.h2d", pipeline=s.pipeline, xla=True,
+                        frames=1, bytes=len(s.inputs) * s.h * s.w * 4):
+            images = {name: jnp.asarray(f.frames[name], jnp.float32)
+                      for name in s.inputs}
         with trace.span("engine.execute", pipeline=s.pipeline, xla=True):
-            out, new_state = ex(f.frames, s.state)
+            out, new_state = ex(images, s.state)
             out.block_until_ready()
         return [out], new_state, ex.vmem_bytes + ex.frame_state_bytes
 
@@ -524,8 +534,8 @@ class VideoEngine:
         queue_wait = (time.perf_counter()
                       - min(f.submitted_at for f in frames))
         self.metrics.observe_queue_wait(queue_wait)
-        with trace.span("engine.step", engine="video", pipeline=s.pipeline,
-                        stream=sid, n_frames=n,
+        with trace.span("engine.step", xla=True, engine="video",
+                        pipeline=s.pipeline, stream=sid, n_frames=n,
                         queue_wait_s=queue_wait) as sp:
             t0 = time.perf_counter()
             served, failed, vmem, rps = self._execute_stream(s, frames)

@@ -18,7 +18,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import algorithms
 from repro.core.codegen import compile_pipeline, tap_name, temporal_taps
 from repro.kernels.stencil_pipeline import (make_batched_pipeline_kernel,
-                                            make_executor)
+                                            make_executor,
+                                            make_video_executor)
 
 W, H = algorithms.RESOLUTIONS["1080p"]
 BATCH, R = 4, 8
@@ -78,3 +79,30 @@ def test_unaligned_row_group_is_refused():
     with pytest.raises(ValueError, match="multiple of 8"):
         make_executor(algorithms.canny_m(), H, W, rows_per_step=4,
                       interpret=False)
+
+
+@pytest.mark.parametrize("name,program", [
+    ("canny-m", "imagen_frame_batch_canny_m"),
+    ("tbackground-t", "imagen_video_step_tbackground_t")])
+def test_device_names_for_v5e(one_chip, no_persistent_cache, name, program):
+    """The served programs and their kernel carry fixed names on the
+    chip, which the device trace shows."""
+    dag, h, w = ALL[name](), 16, 256
+    plan = compile_pipeline(dag, w, rows_per_step=R)
+    arg = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+    images = {"in": arg((BATCH, h, w))}
+    if dag.is_temporal():
+        ex = make_video_executor(dag, h, w, plan=plan, interpret=False,
+                                 chunk=BATCH)
+        lowered = ex._fn.lower(images, {p: arg(s.shape) for p, s in
+                                        ex.init_state().items()})
+    else:
+        ex = make_executor(dag, h, w, batch=BATCH, plan=plan,
+                           interpret=False)
+        lowered = ex._fn.lower(images)
+    text = lowered.compile().as_text()
+    assert f"HloModule jit_{program}," in text
+    kernel = "imagen_stencil_" + name.replace("-", "_")
+    assert any(f"%{kernel}" in ln and "tpu_custom_call" in ln
+               for ln in text.splitlines())
