@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
+from repro.kernels.stencil_pipeline import unstack
 from repro.obs import trace
 from repro.resilience import (AdmissionController, FailedFrame,
                               FallbackLadder, Priority, RejectedFrame,
@@ -301,8 +302,10 @@ class FrameEngine:
             batch_out = ex(inputs)
             batch_out.block_until_ready()
         with trace.span("engine.deliver", pipeline=name, xla=True,
-                        frames=len(reqs)):
-            outs = [batch_out[i] for i in range(len(reqs))]
+                        frames=len(reqs), programs=1):
+            # split every padded row, keep the live ones: one program
+            # per frame shape, whatever the fill
+            outs = list(unstack(batch_out)[:len(reqs)])
         return outs, ex.vmem_bytes
 
     def _run_reference(self, name: str,

@@ -621,6 +621,17 @@ def init_frame_state(depths: dict[str, int], h: int,
             for p, d in depths.items()}
 
 
+def _unstack(x: jnp.ndarray) -> tuple[jnp.ndarray, ...]:
+    return tuple(x[i] for i in range(x.shape[0]))
+
+
+# (B, h, w) -> B separate (h, w) arrays, each a copy of its row: how the
+# engines hand out a batch's or chunk's frames, one host dispatch per
+# batch. jit keys it on the input's shape and dtype, so a padded batch
+# compiles it once per frame shape whatever its fill.
+unstack = _named_jit(_unstack, "imagen_unstack")
+
+
 @dataclasses.dataclass(frozen=True)
 class VideoExecutor:
     """A compiled frame-stream executor — stateless across streams.

@@ -62,7 +62,7 @@ from repro.imaging.metrics import EngineMetrics
 from repro.imaging.plan_cache import PlanCache
 from repro.imaging.tiling import rows_per_step_for_tile
 from repro.kernels import ref
-from repro.kernels.stencil_pipeline import init_frame_state
+from repro.kernels.stencil_pipeline import init_frame_state, unstack
 from repro.obs import trace
 from repro.resilience import (AdmissionController, CancelledFrame,
                               FailedFrame, FallbackLadder, LadderExhausted,
@@ -358,8 +358,8 @@ class VideoEngine:
             out, new_state = ex(ins, s.state)
             out.block_until_ready()
         with trace.span("engine.deliver", pipeline=s.pipeline, xla=True,
-                        frames=n):
-            outs = [out[i] for i in range(n)]
+                        frames=n, programs=1):
+            outs = list(unstack(out))
         return outs, new_state, ex.vmem_bytes + ex.frame_state_bytes
 
     def _run_frame(self, s: VideoSession, f: VideoFrame,

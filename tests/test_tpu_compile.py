@@ -19,7 +19,7 @@ from repro.core import algorithms
 from repro.core.codegen import compile_pipeline, tap_name, temporal_taps
 from repro.kernels.stencil_pipeline import (make_batched_pipeline_kernel,
                                             make_executor,
-                                            make_video_executor)
+                                            make_video_executor, unstack)
 
 W, H = algorithms.RESOLUTIONS["1080p"]
 BATCH, R = 4, 8
@@ -106,3 +106,16 @@ def test_device_names_for_v5e(one_chip, no_persistent_cache, name, program):
     kernel = "imagen_stencil_" + name.replace("-", "_")
     assert any(f"%{kernel}" in ln and "tpu_custom_call" in ln
                for ln in text.splitlines())
+
+
+def test_output_split_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The engines' output split at 1080p, batch 4: a program of its own
+    name holding no kernel, so the trace reduction never counts it as
+    an executor program."""
+    x = jax.ShapeDtypeStruct((BATCH, H, W), jnp.float32, sharding=one_chip)
+    compiled = unstack.lower(x).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_imagen_unstack," in text
+    assert "tpu_custom_call" not in text
+    assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == \
+        [(H, W)] * BATCH
