@@ -63,13 +63,15 @@ def cell():
     # 9 - 2.5 - 1 - 4, 9 - 3.25 - 1 - 3, 9 - 6.25 - 1 - 0.75
     ("step_self_ms", (1.5 + 1.75 + 1.0) / 3),
     ("assemble_ms", (2.5 + 3.25 + 6.25) / 3),
+    ("execute_ms", 1.0),
 ])
 def test_span_reader(cell, run, metric, expect):
     assert cell.reader(metric)(run) == pytest.approx(expect)
 
 
 @pytest.mark.parametrize("metric", ["h2d_ms", "h2d_max_ms", "stack_ms",
-                                    "deliver_ms", "step_self_ms"])
+                                    "deliver_ms", "step_self_ms",
+                                    "execute_ms"])
 def test_span_reader_finds_nothing(cell, run, metric):
     """A program without these spans (one before them) reads nothing."""
     empty = Run(**{**run.__dict__, "spans": []})
@@ -77,16 +79,12 @@ def test_span_reader_finds_nothing(cell, run, metric):
 
 
 SPAN_METRICS = {"h2d_ms", "h2d_max_ms", "stack_ms", "deliver_ms",
-                "step_self_ms"}
+                "step_self_ms", "execute_ms", "assemble_ms"}
 
 
 def test_cells_report_the_span_metrics():
-    """The frame backlog cell reads the serving spans; the video backlog
-    cell keeps the metric set its recorded test expects."""
+    """Both backlog cells read every serving span, the executor call
+    among them."""
     bench = Bench()
-    names = {m["name"]
-             for m in bench.cell("canny-m-1080p.backlog").per_layer}
-    assert SPAN_METRICS | {"assemble_ms"} <= names
-    names = {m["name"]
-             for m in bench.cell("tbackground-t-1080p.backlog").per_layer}
-    assert "assemble_ms" in names and not SPAN_METRICS & names
+    for name in ("canny-m-1080p.backlog", "tbackground-t-1080p.backlog"):
+        assert SPAN_METRICS <= {m["name"] for m in bench.cell(name).per_layer}

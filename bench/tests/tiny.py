@@ -11,19 +11,10 @@ from bench.spec import ROOT, load_json
 
 H, W = 16, 128
 
-# cells (and their configuration) that BENCHMARK.json does not hold: the
+# cells that BENCHMARK.json does not hold, on its configurations: the
 # camera cells' latency tails were not steady on a shared one-chip host
-# (PERF.md, Open questions); the tests still drive every loop, driver and
-# reader through them
-EXTRA_CONFIGS = [
-    {"name": "tbackground-t-1080p", "source": "test",
-     "file": "bench/configs/tbackground-t-1080p.json", "reduced": [],
-     "why": "test"},
-]
-EXTRA_CELLS = [
-    {"name": "tbackground-t-1080p.backlog", "config": "tbackground-t-1080p",
-     "traffic": "backlog4", "chips": 1, "why": "test"},
-]
+# (PERF.md, Open questions); the tests still drive the open loop, the
+# camera traffic and their readers through them
 CAMERA_CELLS = [
     {"name": "tbackground-t-1080p.cams30", "config": "tbackground-t-1080p",
      "traffic": "cams11x30", "chips": 1, "why": "test"},
@@ -42,16 +33,6 @@ def make_root(path, sample: int = 8) -> str:
     references, and the traffic mixes under ``path``; returns it."""
     path = str(path)
     spec = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    have = {c["name"] for c in spec["configs"]}
-    spec["configs"] += [c for c in EXTRA_CONFIGS if c["name"] not in have]
-    have = {w["name"] for w in spec["workloads"]}
-    for w in EXTRA_CELLS:
-        if w["name"] not in have:
-            spec["workloads"].append(w)
-            for m in spec["end_to_end"] + spec["per_layer"]:
-                if "workloads" in m and "canny-m-1080p.backlog" in \
-                        m["workloads"]:
-                    m["workloads"].append(w["name"])
     cams = [c["name"] for c in CAMERA_CELLS]
     spec["workloads"] += CAMERA_CELLS
     for kind, metrics in CAMERA_METRICS.items():
