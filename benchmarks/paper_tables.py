@@ -19,7 +19,7 @@ from repro.core.linebuffer import (ASIC_SRAM_BITS, DP_SIZED, DPLC_SIZED,
 from repro.core.power import memory_power
 
 RES = {"320p": 480, "1080p": 1920}
-ALGOS = list(algorithms.ALGORITHMS)
+ALGOS = list(algorithms.PAPER_ALGORITHMS)
 
 
 def _time(fn, reps=3):

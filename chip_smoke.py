@@ -2,7 +2,7 @@
 """Chip smoke test: the serving path on one TPU at the paper's 1080p.
 
 Drives every registered pipeline through the engines a user calls —
-``FrameEngine`` over the default ``PlanCache`` for the 7 spatial
+``FrameEngine`` over the default ``PlanCache`` for the 8 spatial
 pipelines (native 1920x1080 batches, then one canny-m frame through the
 tiled path), ``VideoEngine`` for the 4 temporal pipelines (one stream
 each, plus tdenoise-t once more at ``prefetch_depth=2``) — on seeded

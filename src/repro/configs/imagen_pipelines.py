@@ -3,7 +3,8 @@ from repro.core import algorithms
 from repro.core.linebuffer import (DP, DPLC, FPGA_DP, FPGA_DPLC, FPGA_SP,
                                    SP, MemConfig)
 
-PIPELINES = dict(algorithms.ALGORITHMS)
+PIPELINES = {n: algorithms.ALGORITHMS[n]
+             for n in algorithms.PAPER_ALGORITHMS}
 # Temporal (multi-frame) pipelines: same compiler, one axis up — frame
 # rings instead of (well, alongside) line buffers. Kept separate from
 # PIPELINES so single-frame sweeps (DSE, paper tables) stay single-frame.

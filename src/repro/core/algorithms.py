@@ -4,6 +4,8 @@ Stage/MC counts match Tbl. 3 exactly (stage counts include the input and
 output stages, per the Darkroom-style DSL). The arithmetic payloads are
 representative stencil math (separable Gaussian, Sobel, Laplacian, NMS,
 unsharp, 18x1 cross-correlation) so functional tests are meaningful.
+``sift-dog``, outside Tbl. 3, is one octave of SIFT's DoG detector as
+OpenCV computes it, departures listed in its docstring.
 
 Window convention (matches the scheduling model / simulator): the window
 for output pixel (r, x) covers rows r-sh+1..r and cols x-sw+1..x of each
@@ -26,7 +28,9 @@ from .dsl import Pipeline
 # ``win[..., dy, dx]`` (temporal: ``win[..., dt, dy, dx]``) with
 # non-negative static ints and read for its ``.shape``, nothing else: the
 # reference passes real arrays, the fused kernel a lazy view of its row
-# slabs (kernels.stencil_pipeline._WindowView) that obeys the same rules.
+# slabs (kernels.stencil_pipeline._WindowView) that obeys the same rules,
+# and PipelineDAG.taps a counter of the indices read (dag.window_index
+# checks a key against the rules).
 def _single(wins):
     (v,) = wins.values()
     return v
@@ -214,11 +218,149 @@ def denoise_m() -> PipelineDAG:
     return p.build()
 
 
+# ----------------------------------------------------- SIFT scale space
+# One octave of SIFT's difference-of-Gaussians detector (D. G. Lowe,
+# IJCV 60(2), 2004, Sec. 3-4) at OpenCV's cv::SIFT::create defaults.
+SIFT_SIGMA = 1.6            # sigma of the first level
+SIFT_INIT_SIGMA = 0.5       # blur the input is assumed to carry
+SIFT_LAYERS = 3             # nOctaveLayers: the DoG layers tested
+SIFT_CONTRAST = 0.04        # contrastThreshold
+SIFT_EDGE = 10.0            # edgeThreshold
+
+
+def gauss_taps(sigma: float) -> np.ndarray:
+    """OpenCV's ``getGaussianKernel`` for float images: ``round(8 sigma
+    + 1) | 1`` taps of ``exp(-x^2 / 2 sigma^2)``, ``x`` centred, summed
+    to 1 in float64 and stored as float32."""
+    n = round(8 * sigma + 1) | 1
+    x = np.arange(n) - (n - 1) / 2
+    g = np.exp(-0.5 / (sigma * sigma) * x * x)
+    return (g / g.sum()).astype(np.float32)
+
+
+def sift_sigmas() -> list[float]:
+    """The incremental blur of each of the ``SIFT_LAYERS + 3`` Gaussian
+    levels of an octave, as OpenCV's ``buildGaussianPyramid`` gives
+    them: level 0 takes the input from ``SIFT_INIT_SIGMA`` to
+    ``SIFT_SIGMA``, level i from ``SIFT_SIGMA k^(i-1)`` to ``SIFT_SIGMA
+    k^i`` with ``k = 2^(1/SIFT_LAYERS)``."""
+    k = 2.0 ** (1.0 / SIFT_LAYERS)
+    sig = [math.sqrt(SIFT_SIGMA ** 2 - SIFT_INIT_SIGMA ** 2)]
+    for i in range(1, SIFT_LAYERS + 3):
+        prev = SIFT_SIGMA * k ** (i - 1)
+        sig.append(math.sqrt((prev * k) ** 2 - prev ** 2))
+    return sig
+
+
+def dog_fn(hi: str, lo: str):
+    """``hi - lo``: the next Gaussian level less the (aligned) last."""
+    def fn(wins):
+        return wins[hi][..., 0, 0] - wins[lo][..., 0, 0]
+    return fn
+
+
+def extremum_fn(below: str, centre: str, above: str):
+    """SIFT's keypoint test on three DoG planes, read through windows
+    whose top-left 3x3 lines up: ``|c|`` where the centre sample ``c`` of
+    ``centre`` is a keypoint, else 0. A keypoint is a 26-neighbour
+    extremum (``c > 0`` and ``c`` >= all, or ``c < 0`` and <= all) above
+    OpenCV's pre-threshold ``0.5 contrastThreshold / nOctaveLayers`` and
+    its contrast test ``nOctaveLayers |c| >= contrastThreshold`` (taken
+    on the sample: no sub-pixel fit), whose Hessian on the centre plane
+    passes the edge test ``det > 0``, ``tr^2 r < (r + 1)^2 det``."""
+    pre = 0.5 * SIFT_CONTRAST / SIFT_LAYERS
+    r = SIFT_EDGE
+
+    def fn(wins):
+        planes = [wins[below], wins[centre], wins[above]]
+        mid = planes[1]
+        c = mid[..., 1, 1]
+        vals = [p[..., dy, dx] for p in planes
+                for dy in range(3) for dx in range(3)]
+        mx = reduce(jnp.maximum, vals)
+        mn = reduce(jnp.minimum, vals)
+        extremum = ((c > 0) & (c >= mx)) | ((c < 0) & (c <= mn))
+        a = jnp.abs(c)
+        dxx = mid[..., 1, 2] + mid[..., 1, 0] - 2.0 * c
+        dyy = mid[..., 2, 1] + mid[..., 0, 1] - 2.0 * c
+        dxy = (mid[..., 2, 2] - mid[..., 2, 0] - mid[..., 0, 2]
+               + mid[..., 0, 0]) * 0.25
+        tr = dxx + dyy
+        det = dxx * dyy - dxy * dxy
+        keep = (extremum & (a > pre) & (SIFT_LAYERS * a >= SIFT_CONTRAST)
+                & (det > 0) & (tr * tr * r < (r + 1) ** 2 * det))
+        return jnp.where(keep, a, 0.0)
+    return fn
+
+
+def max_fn(wins):
+    return reduce(jnp.maximum, (v[..., 0, 0] for v in wins.values()))
+
+
+def sift_dog() -> PipelineDAG:
+    """23 stages, 8 MC — the first octave of SIFT's DoG detector.
+
+    Six Gaussian levels (separable ``gauss_taps`` blurs of 13, 11, 13,
+    17, 21, 27 taps, each on the level before), five DoG planes, the
+    keypoint test on the three middle ones, and their max as the one
+    output: a dense map holding ``|DoG|`` at each keypoint, 0 elsewhere.
+
+    Windows are causal, so a blur of ``n = 2h + 1`` taps delays its
+    level by ``h`` rows and columns. Reads that must meet at one source
+    pixel are delay windows: a producer ``e`` pixels behind is read
+    through an ``(e + 1) x (e + 1)`` window at element [0, 0] (or, for a
+    3x3 neighbourhood, at its top-left 3x3).
+
+    Departures from OpenCV's octave (also in the benchmark
+    configuration's ``assumed``): every stage reads zeros above and
+    left of the frame, not reflect-101 borders; output (r, x) is the
+    centred detector at (r - 49, x - 49); no 5-pixel border skip,
+    sub-pixel refinement, orientation or descriptor; the dense response
+    map is the output; thresholds apply to normalised [0, 1] frames,
+    without OpenCV's floor to whole grey levels; the octave is built from
+    the input itself (``firstOctave = 0``, no doubled image).
+    """
+    p = Pipeline("sift-dog")
+    taps = [gauss_taps(s) for s in sift_sigmas()]
+    half = [(len(t) - 1) // 2 for t in taps]
+    levels = [p.input("in")]
+    for i, t in enumerate(taps):
+        n = len(t)
+        gh = p.stage(f"g{i}h", [(levels[-1], 1, n)], conv_fn(t[None, :]))
+        levels.append(p.stage(f"g{i}v", [(gh, n, 1)], conv_fn(t[:, None])))
+    levels = levels[1:]
+    dogs = []
+    for i in range(len(levels) - 1):   # level i is half[i + 1] behind i + 1
+        e = half[i + 1] + 1
+        hi, lo = levels[i + 1], levels[i]
+        dogs.append(p.stage(f"d{i}", [(hi, 1, 1), (lo, e, e)],
+                            dog_fn(hi.name, lo.name)))
+    layers, lags = [], []
+    for k in range(1, len(dogs) - 1):
+        # plane k + 1 is half[k + 2] ahead of plane k, which is
+        # half[k + 1] ahead of plane k - 1: both are read that far back
+        below, centre, above = dogs[k - 1], dogs[k], dogs[k + 1]
+        a, b = 3 + half[k + 1] + half[k + 2], 3 + half[k + 2]
+        layers.append(p.stage(
+            f"x{k}", [(below, a, a), (centre, b, b), (above, 3, 3)],
+            extremum_fn(below.name, centre.name, above.name)))
+        # layer k lines up with plane k + 1, this far behind the last
+        lags.append(sum(half[k + 3:]))
+    comb = p.stage("comb", [(x, e + 1, e + 1) for x, e in zip(layers, lags)],
+                   max_fn)
+    p.output("out", [(comb, 1, 1)])
+    return p.build()
+
+
 ALGORITHMS = {
     "canny-s": canny_s, "canny-m": canny_m,
     "harris-s": harris_s, "harris-m": harris_m,
     "unsharp-m": unsharp_m, "xcorr-m": xcorr_m, "denoise-m": denoise_m,
+    "sift-dog": sift_dog,
 }
+# the paper's Tbl. 3 pipelines: what its tables and figures reproduce
+PAPER_ALGORITHMS = ("canny-s", "canny-m", "harris-s", "harris-m",
+                    "unsharp-m", "xcorr-m", "denoise-m")
 
 
 # ---------------------------------------------------- temporal window fns

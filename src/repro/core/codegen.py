@@ -427,6 +427,10 @@ def compile_pipeline(dag: PipelineDAG, w: int,
                                  max_pad_iters, rows_per_step, frame_h,
                                  mem_cfg, schedule, prefetch_depth)
         sp.set(vmem_ring_bytes=plan.vmem_ring_bytes)
+        if trace.enabled():
+            sp.set(stages=dag.num_stages(), edges=len(dag.edges),
+                   mc_stages=len(dag.multi_consumer_stages()),
+                   taps=dag.taps)
         return plan
 
 

@@ -13,6 +13,7 @@ itself only ever looks at the graph structure and stencil heights.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Mapping, Sequence
 
 
@@ -57,6 +58,39 @@ def window_keys(edges: Sequence[Edge]) -> list[str]:
             keys.append(f"{e.producer}#{e.st}x{e.sh}x{e.sw}")
         seen.add(e.producer)
     return keys
+
+
+def window_index(key, shape: tuple[int, ...]) -> tuple[int, ...]:
+    """The window element ``win[key]`` names, for a window of ``shape``
+    (two leading pixel axes, then ``[st,] sh, sw``). A stage function
+    may only index a window as ``win[..., dy, dx]`` (temporal
+    ``win[..., dt, dy, dx]``) with non-negative static ints inside it;
+    every window a function is handed checks its keys here."""
+    n = len(shape) - 2
+    if not (isinstance(key, tuple) and len(key) == n + 1
+            and key[0] is Ellipsis):
+        raise TypeError(f"a window takes [..., {'dt, ' * (n == 3)}dy, dx], "
+                        f"got {key!r}")
+    idx = key[1:]
+    for i, ext in zip(idx, shape[-n:]):
+        if not isinstance(i, int) or not 0 <= i < ext:
+            raise IndexError(f"window index {idx} outside {shape[-n:]}")
+    return idx
+
+
+class _TapCounter:
+    """A window that keeps the stage-function contract and records the
+    distinct indices a function reads it at; each read is a (1, 1)
+    plane of zeros."""
+
+    def __init__(self, e: Edge):
+        self.shape = (1, 1) + ((e.st,) if e.st > 1 else ()) + (e.sh, e.sw)
+        self.read: set[tuple[int, ...]] = set()
+
+    def __getitem__(self, key):
+        import jax.numpy as jnp     # the graph itself needs no jax
+        self.read.add(window_index(key, self.shape))
+        return jnp.zeros((1, 1), jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,20 +192,36 @@ class PipelineDAG:
     def num_stages(self) -> int:
         return len(self.stages)
 
-    def cumulative_extent(self, temporal: bool = False
-                          ) -> tuple[int, int] | tuple[int, int, int]:
-        """(up, left) — or (back, up, left) — dependency halo of the output.
+    @functools.cached_property
+    def taps(self) -> int:
+        """Window elements the stage functions read per output pixel: for
+        each read of each stage, the distinct (dt, dy, dx) its function
+        indexes; a relay or the output reads its one element. Counted
+        once per DAG by tracing each function abstractly (nothing runs),
+        so it is the pipeline's work, not how an executor does it."""
+        import jax                  # the graph itself needs no jax
+        taps = 0
+        for name in self.topo_order:
+            st, ins = self.stages[name], self.in_edges(name)
+            if st.is_input:
+                continue
+            if st.fn is None:
+                taps += 1
+                continue
+            wins = {k: _TapCounter(e) for k, e in zip(window_keys(ins), ins)}
+            jax.eval_shape(lambda: st.fn(wins))
+            taps += sum(len(w.read) for w in wins.values())
+        return taps
+
+    def stage_extents(self, temporal: bool = False
+                      ) -> dict[str, tuple[int, int] | tuple[int, int, int]]:
+        """Each stage's (up, left) — or (back, up, left) — dependency halo.
 
         Windows are causal (bottom-right aligned): stage output pixel
         (r, x) of frame t reads producer frames t-st+1..t, rows
         r-sh+1..r, cols x-sw+1..x. Chaining edges therefore accumulates
-        (st-1, sh-1, sw-1) per hop; joins take the max over in-edges. The
-        spatial legs are the halo a tile executor must prepend (above/
-        left) so every output pixel of the tile sees its full input
-        dependency cone; the temporal leg ``back`` is how many *past*
-        input frames the current output frame depends on — the warm-up
-        depth of a streaming video session. ``temporal=False`` (the
-        default) keeps the historical 2-tuple for spatial callers.
+        (st-1, sh-1, sw-1) per hop; joins take the max over in-edges;
+        inputs are (0, 0, 0).
         """
         ext: dict[str, tuple[int, int, int]] = {}
         for name in self.topo_order:
@@ -183,8 +233,20 @@ class PipelineDAG:
                 max(ext[e.producer][0] + e.st - 1 for e in ins),
                 max(ext[e.producer][1] + e.sh - 1 for e in ins),
                 max(ext[e.producer][2] + e.sw - 1 for e in ins))
-        back, up, left = ext[self.output_stages()[0]]
-        return (back, up, left) if temporal else (up, left)
+        return ext if temporal else {n: e[1:] for n, e in ext.items()}
+
+    def cumulative_extent(self, temporal: bool = False
+                          ) -> tuple[int, int] | tuple[int, int, int]:
+        """The output's entry of :meth:`stage_extents`.
+
+        The spatial legs are the halo a tile executor must prepend (above/
+        left) so every output pixel of the tile sees its full input
+        dependency cone; the temporal leg ``back`` is how many *past*
+        input frames the current output frame depends on — the warm-up
+        depth of a streaming video session. ``temporal=False`` (the
+        default) keeps the historical 2-tuple for spatial callers.
+        """
+        return self.stage_extents(temporal)[self.output_stages()[0]]
 
     def temporal_depths(self) -> dict[str, int]:
         """Producer -> max temporal extent over its out-edges (entries > 1

@@ -101,7 +101,7 @@ class TuneStats:
     n_compiled: int = 0                 # candidates fully compiled+scored
     n_sched_memo_hits: int = 0          # solves saved by signature memo
     space_size: int = 0                 # |options| ** |owners|
-    truncated: bool = False             # space exceeded max_candidates
+    truncated: bool = False             # draws reached max_candidates
     tune_s: float = 0.0
 
 
@@ -186,11 +186,12 @@ def autotune(dag: PipelineDAG, w: int,
 
     ``options`` is the per-owner choice set; non-owner stages keep the
     ``default`` config (their entry never touches SRAM). ``max_candidates``
-    bounds *compiled* candidates — pruned combos are free — and the
-    cartesian product is truncated beyond it (uniform combos are always
-    evaluated first, so truncation can only cost exotic mixes, never the
-    serving default). ``branch_cap`` prunes combos whose port OR-groups
-    would explode into more MILP branches than it allows.
+    bounds the combos drawn from the space, pruned ones included, so a
+    space whose mixes are all pruned still ends; the cartesian product
+    is truncated beyond it (uniform combos are always evaluated first,
+    so truncation can only cost exotic mixes, never the serving
+    default). ``branch_cap`` prunes combos whose port OR-groups would
+    explode into more MILP branches than it allows.
 
     Every returned candidate compiled cleanly and passed the simulator's
     R1/R2/R3 validation inside compile_pipeline; scoring runs one more
@@ -280,7 +281,7 @@ def _autotune(dag: PipelineDAG, w: int, options, default, rows_per_step,
                                for p in owners))
 
     for combo in _enumerate(owners, options, base):
-        if stats.n_compiled >= max_candidates:
+        if stats.n_enumerated >= max_candidates:
             stats.truncated = True
             break
         cfg_of = dict(base)

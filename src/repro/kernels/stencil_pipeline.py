@@ -67,7 +67,7 @@ from repro.core.codegen import (PipelinePlan, frame_outputs,
                                 prefetch_ring_bytes, row_group_rings,
                                 tap_name, temporal_tap_rings, temporal_taps)
 from repro.obs import trace
-from repro.core.dag import PipelineDAG, window_keys
+from repro.core.dag import PipelineDAG, window_index, window_keys
 
 
 def default_interpret() -> bool:
@@ -156,16 +156,7 @@ class _WindowView:
                       + ((len(slabs),) if temporal else ()) + (sh, sw))
 
     def __getitem__(self, key) -> jnp.ndarray:
-        n = 3 if self._temporal else 2
-        if not (isinstance(key, tuple) and len(key) == n + 1
-                and key[0] is Ellipsis):
-            raise TypeError(f"window view takes [..., {'dt, ' * (n == 3)}"
-                            f"dy, dx], got {key!r}")
-        idx = key[1:]
-        for i, ext in zip(idx, self.shape[-n:]):
-            if not isinstance(i, int) or not 0 <= i < ext:
-                raise IndexError(f"window index {idx} outside "
-                                 f"{self.shape[-n:]}")
+        idx = window_index(key, self.shape)
         dt, dy, dx = idx if self._temporal else (0, *idx)
         cols = self._cols.get((dt, dx))
         if cols is None:
@@ -569,6 +560,9 @@ class StencilExecutor:
     prefetch_depth: int
     vmem_bytes: int
     interpret: bool
+    # window elements the stage functions read per output pixel
+    # (PipelineDAG.taps, counted once per DAG)
+    taps: int
     # the ImaGen plan this executor embodies (None for plan-less ad-hoc
     # builds): the serving stack reports per-executor memory/power
     # accounting — e.g. an autotuned config's SRAM bill — through it
@@ -583,7 +577,7 @@ class StencilExecutor:
         # XLA profile when both are captured
         with trace.span("executor.call", xla=True, pipeline=self.dag.name,
                         batch=self.batch, rows_per_step=self.rows_per_step,
-                        prefetch_depth=self.prefetch_depth):
+                        prefetch_depth=self.prefetch_depth, taps=self.taps):
             return self._fn(images)
 
     @property
@@ -608,7 +602,8 @@ def make_executor(dag: PipelineDAG, h: int, w: int,
                                     rows_per_step=r, prefetch_depth=d)
     return StencilExecutor(dag=dag, h=h, w=w, batch=batch, rows_per_step=r,
                            prefetch_depth=d, vmem_bytes=vmem,
-                           interpret=interpret, plan=plan, _fn=fn)
+                           interpret=interpret, taps=dag.taps,
+                           plan=plan, _fn=fn)
 
 
 def init_frame_state(depths: dict[str, int], h: int,
@@ -659,6 +654,7 @@ class VideoExecutor:
     vmem_bytes: int                 # VMEM rings (spatial + tap + prefetch)
     frame_state_bytes: int          # device-resident frame-ring state
     interpret: bool
+    taps: int                       # as StencilExecutor.taps
     depths: dict = dataclasses.field(repr=False)   # producer -> frames
     # compiled ImaGen plan (see StencilExecutor.plan) — None when ad hoc
     plan: PipelinePlan | None = dataclasses.field(repr=False, default=None)
@@ -675,7 +671,7 @@ class VideoExecutor:
                  ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
         with trace.span("executor.call", xla=True, pipeline=self.dag.name,
                         chunk=self.chunk, rows_per_step=self.rows_per_step,
-                        prefetch_depth=self.prefetch_depth):
+                        prefetch_depth=self.prefetch_depth, taps=self.taps):
             return self._fn(images, state)
 
     @property
@@ -740,6 +736,7 @@ def make_video_executor(dag: PipelineDAG, h: int, w: int,
                          prefetch_depth=d, vmem_bytes=vmem,
                          frame_state_bytes=sum((d - 1) * h * w * 4
                                                for d in depths.values()),
-                         interpret=interpret, depths=dict(depths), plan=plan,
+                         interpret=interpret, taps=dag.taps,
+                         depths=dict(depths), plan=plan,
                          _fn=_named_jit(step, _device_name(
                              "imagen_video_step", dag)))

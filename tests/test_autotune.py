@@ -100,6 +100,18 @@ def test_infeasible_default_raises():
 
 
 # ------------------------------------------------------------- plan cache
+def test_pruned_draws_count_toward_the_cap():
+    """sift-dog's mixed combos all exceed the branch cap; the search
+    still ends at ``max_candidates`` draws, on the serving path too."""
+    cache = PlanCache()
+    plan = cache.plan_for("sift-dog", W, tune=True)
+    res = cache.tuning_for("sift-dog", W)
+    s = res.stats
+    assert s.truncated and s.n_enumerated == cache.tune_max_candidates
+    assert s.n_pruned_branches > 0 and s.n_compiled < s.n_enumerated
+    assert plan.vmem_ring_bytes == res.best.vmem_bytes
+
+
 def test_plan_cache_tunes_once_and_derives_siblings():
     cache = PlanCache()
     p1 = cache.plan_for("unsharp-m", W, rows_per_step=1, tune=True)

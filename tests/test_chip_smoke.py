@@ -14,6 +14,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core import algorithms
 from repro.imaging import PlanCache
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -36,7 +37,7 @@ def cache():
 def test_spatial_phase_small(smoke, cache):
     rep = smoke.spatial_phase(cache, np.random.default_rng(0), 16, 64,
                               n_frames=4, max_batch=2)
-    assert len(rep) == 7
+    assert len(rep) == len(algorithms.ALGORITHMS)
     assert all(r["max_scale_ulp"] <= smoke.SCALE_ULP_BOUND
                for r in rep.values())
 

@@ -386,3 +386,18 @@ def test_engines_silent_when_tracing_disabled():
     eng = FrameEngine(max_batch=2, max_pending=8)
     assert len(eng.run([_frame_req(0)])) == 1
     assert trace.events() == []       # zero spans recorded
+
+
+def test_compile_span_counts_the_dag(global_trace):
+    """``compile.pipeline`` carries the DAG's stages, edges, multi-consumer
+    stages and window taps; with tracing off the taps are not counted."""
+    from repro.core import algorithms, codegen
+    codegen.compile_pipeline(algorithms.canny_m(), 32)
+    (sp,) = [e for e in trace.events() if e.name == "compile.pipeline"]
+    assert {k: sp.attrs[k] for k in ("stages", "edges", "mc_stages",
+                                     "taps")} == {
+        "stages": 10, "edges": 10, "mc_stages": 1, "taps": 38}
+    trace.disable()
+    dag = algorithms.canny_m()
+    codegen.compile_pipeline(dag, 32)
+    assert "taps" not in vars(dag)      # the cached count was never made

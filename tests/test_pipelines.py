@@ -15,6 +15,15 @@ TABLE3 = {  # name -> (stages, mc_stages)
 }
 
 
+def test_paper_catalogue_is_table3():
+    """The paper tables reproduce Tbl. 3 alone, not every registered
+    pipeline."""
+    from repro.configs import PIPELINES
+    assert sorted(algorithms.PAPER_ALGORITHMS) == sorted(TABLE3)
+    assert sorted(PIPELINES) == sorted(TABLE3)
+    assert set(TABLE3) < set(algorithms.ALGORITHMS)
+
+
 @pytest.mark.parametrize("name", list(TABLE3))
 def test_table3_structure(name):
     dag = algorithms.ALGORITHMS[name]()

@@ -3,6 +3,7 @@ host -> device copy (``engine.h2d``) and its stack (``engine.stack``),
 output delivery (``engine.deliver``), their attributes, their place in
 the span tree, and their profiler annotations, which share the device
 trace's clock."""
+import dataclasses
 import glob
 
 import jax
@@ -202,3 +203,22 @@ def test_step_breakdown_parts_partition_the_step():
     assert b["step_self_s"] == pytest.approx(38e-6)
     parts = ("assemble_s", "execute_s", "deliver_s", "step_self_s")
     assert sum(b[k] for k in parts) == pytest.approx(b["step_s"])
+
+
+@pytest.mark.parametrize("name,taps", [
+    ("canny-m", 38), ("tbackground-t", 11), ("sift-dog", 299)])
+def test_executor_call_carries_taps(global_trace, name, taps):
+    """``executor.call`` of both executors names the window elements the
+    pipeline's stage functions read per output pixel."""
+    dag = {**algorithms.ALGORITHMS, **algorithms.VIDEO_ALGORITHMS}[name]()
+    args = ({"in": np.zeros((2, 16, 128), np.float32)},)
+    if dag.is_temporal():
+        ex = make_video_executor(dag, 16, 128, rows_per_step=8, chunk=2)
+        args += (ex.init_state(),)
+    else:
+        ex = make_executor(dag, 16, 128, batch=2, rows_per_step=8)
+    assert ex.taps == taps
+    # the span, not the kernel, is under test
+    dataclasses.replace(ex, _fn=lambda *a: None)(*args)
+    (call,) = [e for e in trace.events() if e.name == "executor.call"]
+    assert call.attrs["taps"] == taps

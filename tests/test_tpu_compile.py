@@ -57,7 +57,7 @@ def no_persistent_cache():
 
 @pytest.mark.parametrize("name,depth,h,w", [
     ("unsharp-m", 1, H, W), ("canny-m", 1, H, W), ("xcorr-m", 1, H, W),
-    ("tdenoise-t", 1, H, W), ("tdenoise-t", 2, H, W),
+    ("tdenoise-t", 1, H, W), ("tdenoise-t", 2, H, W), ("sift-dog", 1, H, W),
     ("canny-m", 1, 128, 128)])        # the engine's default tile
 def test_fused_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
                                         name, depth, h, w):
