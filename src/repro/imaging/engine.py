@@ -13,7 +13,10 @@ scheduling:
     request is oldest, then fills up to ``max_batch`` slots with same-shape
     frames from that queue (FIFO, so per-pipeline completion order equals
     submission order). Partial batches run with zero-filled idle slots —
-    the executor is compiled once at ``max_batch`` and reused.
+    the executor is compiled once at ``max_batch`` and reused. In strict
+    mode, when the batch after the one a step dispatched is full and
+    untiled, the step copies and stacks it to the device before waiting
+    for the running kernel; the next ``step()`` executes it.
   * **tiling dispatch** — frames no larger than ``tile_shape`` run through
     the batched executor directly; larger frames go through the tiled
     executor one request at a time (each frame's tiles ride the batched
@@ -58,7 +61,8 @@ from repro.resilience import (AdmissionController, FailedFrame,
                               ResilienceConfig, ShedFrame, overdue_s,
                               pick_shed_victim, screen_frames,
                               split_expired)
-from repro.serve.scheduling import BoundedFifo, assemble_batch, pad_batch
+from repro.serve.scheduling import (BoundedFifo, assemble_batch, pad_batch,
+                                    peek_batch)
 
 from .metrics import EngineMetrics
 from .plan_cache import PlanCache
@@ -78,6 +82,17 @@ class FrameRequest:
     @property
     def shape(self) -> tuple[int, int]:
         return tuple(next(iter(self.frames.values())).shape)
+
+
+@dataclasses.dataclass
+class _Prepared:
+    """A full batch popped, copied and stacked while the previous
+    batch's kernel ran; the next ``step()`` executes it. ``error`` holds
+    what its copy or stack raised, so the batch fails on its own step."""
+    pipeline: str
+    reqs: list[FrameRequest]
+    inputs: dict | None = None
+    error: Exception | None = None
 
 
 @dataclasses.dataclass
@@ -129,6 +144,8 @@ class FrameEngine:
         # shed outcomes produced at admission time (overload evictions)
         # or by the expiry sweep; flushed into the next step()'s results
         self._shed_outbox: list[ShedFrame] = []
+        # strict mode: the next batch, made ready under the running one
+        self._prepared: _Prepared | None = None
         if resilience is not None:
             self._admission = AdmissionController(
                 resilience.rate, resilience.burst, clock=trace.now)
@@ -265,12 +282,61 @@ class FrameEngine:
 
     @property
     def pending(self) -> int:
-        return sum(len(q) for q in self._queues.values())
+        prepared = len(self._prepared.reqs) if self._prepared else 0
+        return prepared + sum(len(q) for q in self._queues.values())
 
     # ------------------------------------------------------------ execution
+    def _tiled(self, shape: tuple[int, int]) -> bool:
+        th, tw = self.tile_shape
+        return shape[0] > th or shape[1] > tw
+
+    def _assemble(self, name: str, reqs: list[FrameRequest], h: int, w: int,
+                  prefetched: bool = False) -> dict:
+        """Copy a batch's frames to the device and stack them, padded to
+        ``max_batch``: the executor's inputs."""
+        feeds = self.cache.dag_for(name).input_stages()
+        with trace.span("engine.assemble", pipeline=name, xla=True,
+                        prefetched=prefetched):
+            with trace.span("engine.h2d", pipeline=name, xla=True,
+                            frames=len(reqs),
+                            bytes=len(reqs) * len(feeds) * h * w * 4):
+                copies = {n: [jnp.asarray(r.frames[n], jnp.float32)
+                              for r in reqs] for n in feeds}
+            with trace.span("engine.stack", pipeline=name, xla=True):
+                return {n: jnp.stack(pad_batch(
+                    c, self.max_batch,
+                    lambda: jnp.zeros((h, w), jnp.float32)))
+                    for n, c in copies.items()}
+
+    def _prefetch(self) -> None:
+        """Pop the next batch and copy and stack it now, while the batch
+        just dispatched runs on the device. Only a full batch that the
+        batched executor serves is taken: a partial one may still fill
+        up, and the tiled path copies per tile."""
+        name, reqs = peek_batch(self._queues, self.max_batch,
+                                age_of=lambda r: r.submitted_at,
+                                compatible=lambda a, b: a.shape == b.shape)
+        if len(reqs) < self.max_batch or self._tiled(reqs[0].shape):
+            return
+        q = self._queues[name]
+        for _ in reqs:
+            q.pop()
+        self._prepared = prep = _Prepared(name, reqs)
+        self.metrics.batches_prefetched += 1
+        try:
+            prep.inputs = self._assemble(name, reqs, *reqs[0].shape,
+                                         prefetched=True)
+        except Exception as e:  # noqa: BLE001 - the batch is popped:
+            prep.error = e      # it fails on its own step, not this one
+
     def _run_compiled(self, name: str, reqs: list[FrameRequest],
-                      h: int, w: int, tiled: bool, rps: int, tune: bool
-                      ) -> tuple[list, int]:
+                      h: int, w: int, tiled: bool, rps: int, tune: bool,
+                      inputs: dict | None = None) -> tuple[list, int]:
+        """Run one batch; ``inputs``: the batch already copied and
+        stacked (a prepared batch). Strict mode prepares the next full
+        batch between dispatch and the wait for this one; resilient
+        mode's ladder has to see this batch end before the next
+        starts."""
         th, tw = self.tile_shape
         if tiled:
             with trace.span("engine.execute", pipeline=name, xla=True):
@@ -285,21 +351,12 @@ class FrameEngine:
         ex = self.cache.executor_for(name, h, w, batch=self.max_batch,
                                      rows_per_step=rps, tune=tune,
                                      prefetch_depth=self.prefetch_depth)
-        feeds = self.cache.dag_for(name).input_stages()
-        with trace.span("engine.assemble", pipeline=name, xla=True):
-            with trace.span("engine.h2d", pipeline=name, xla=True,
-                            frames=len(reqs),
-                            bytes=len(reqs) * len(feeds) * h * w * 4):
-                copies = {n: [jnp.asarray(r.frames[n], jnp.float32)
-                              for r in reqs] for n in feeds}
-            with trace.span("engine.stack", pipeline=name, xla=True):
-                inputs = {n: jnp.stack(pad_batch(
-                    c, self.max_batch,
-                    lambda: jnp.zeros((h, w), jnp.float32)))
-                    for n, c in copies.items()}
-            del copies      # the per-frame copies die once stacked
+        if inputs is None:
+            inputs = self._assemble(name, reqs, h, w)
         with trace.span("engine.execute", pipeline=name, xla=True):
             batch_out = ex(inputs)
+            if self.resilience is None:
+                self._prefetch()
             batch_out.block_until_ready()
         with trace.span("engine.deliver", pipeline=name, xla=True,
                         frames=len(reqs), programs=1):
@@ -328,13 +385,19 @@ class FrameEngine:
         return "tuned" if self.autotune else "default"
 
     def _execute(self, name: str, reqs: list[FrameRequest], h: int, w: int,
-                 tiled: bool, rps: int) -> tuple[list, int, str]:
+                 tiled: bool, rps: int, prepared: _Prepared | None = None
+                 ) -> tuple[list, int, str]:
         """Run a batch; returns (outputs, vmem_bytes, rung). Resilient
         mode descends the fallback ladder; strict mode runs the primary
-        path directly (exceptions propagate to step()'s failure path)."""
+        path directly (exceptions propagate to step()'s failure path),
+        starting from ``prepared`` inputs when the last step made them,
+        and prepares the next full batch while this one runs."""
         if self.resilience is None:
-            outs, vmem = self._run_compiled(name, reqs, h, w, tiled, rps,
-                                            tune=self.autotune)
+            if prepared is not None and prepared.error is not None:
+                raise prepared.error
+            outs, vmem = self._run_compiled(
+                name, reqs, h, w, tiled, rps, tune=self.autotune,
+                inputs=prepared.inputs if prepared else None)
             return outs, vmem, self._primary_rung
         rungs = []
         if self.autotune:
@@ -361,10 +424,14 @@ class FrameEngine:
         if self._shed_outbox:
             results, self._shed_outbox = self._shed_outbox, []
         self._pending_gauge.set(self.pending)
-        name, reqs = assemble_batch(
-            self._queues, self.max_batch,
-            age_of=lambda r: r.submitted_at,
-            compatible=lambda a, b: a.shape == b.shape)
+        prepared, self._prepared = self._prepared, None
+        if prepared is not None:
+            name, reqs = prepared.pipeline, prepared.reqs
+        else:
+            name, reqs = assemble_batch(
+                self._queues, self.max_batch,
+                age_of=lambda r: r.submitted_at,
+                compatible=lambda a, b: a.shape == b.shape)
         if not reqs:
             return results
         # queue wait: how long the batch's oldest frame sat admitted but
@@ -374,7 +441,7 @@ class FrameEngine:
         self.metrics.observe_queue_wait(queue_wait)
         h, w = reqs[0].shape
         th, tw = self.tile_shape
-        tiled = h > th or w > tw
+        tiled = self._tiled((h, w))
         # the row-group factor that actually executes: clamped by the tile
         # height on the tiled path, by the frame height otherwise
         rps = rows_per_step_for_tile(min(th, h) if tiled else h,
@@ -385,7 +452,7 @@ class FrameEngine:
             t0 = time.perf_counter()
             try:
                 outs, vmem, rung = self._execute(name, reqs, h, w,
-                                                 tiled, rps)
+                                                 tiled, rps, prepared)
             except Exception as e:  # noqa: BLE001 - structured failure:
                 # the batch is already popped; losing the exception here
                 # would strand it, raising would strand the *rest* of

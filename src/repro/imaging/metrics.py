@@ -32,6 +32,8 @@ _COUNTERS = {
     "executor_retries": "executor/compile attempts retried with backoff",
     "fallback_frames": "frames served by a non-primary ladder rung",
     "batches": "executor batches dispatched",
+    "batches_prefetched": "batches copied and stacked while the previous "
+                          "batch ran",
     "execute_s": "seconds inside executor calls (device-synchronous)",
 }
 
@@ -157,6 +159,7 @@ class EngineMetrics:
             "frames_in_flight": self.in_flight,
             "reconciliation": self.reconcile(),
             "batches": self.batches,
+            "batches_prefetched": self.batches_prefetched,
             "mean_batch_fill": self.batch_fill.mean,
             "fps_wall": self.frames_completed / wall if wall > 0 else 0.0,
             "fps_execute": (self.frames_completed / self.execute_s

@@ -32,7 +32,9 @@ buffering depth an autotuner axis (ROADMAP).
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
+import math
 import time
 from typing import Callable, Sequence
 
@@ -254,15 +256,30 @@ def step_breakdown(trace_data: dict, pipeline: str) -> dict | None:
     ``engine.assemble`` / ``engine.execute`` / ``engine.deliver``
     children, and the step *self* time left over (batching, results,
     metrics — computed with the flame summary's containment
-    arithmetic). Returns seconds, or None when the trace holds no
-    matching step spans; the returned parts feed
-    :func:`repro.perf.model.exact_fractions` so the report's time split
-    provably partitions the step total.
+    arithmetic). A batch assembled while the previous batch ran (a
+    prefetched ``engine.assemble``, nested in that batch's
+    ``engine.execute``) counts as assembly and is taken out of the
+    execute time, so no span counts twice. Returns seconds, or None
+    when the trace holds no matching step spans; the returned parts
+    feed :func:`repro.perf.model.exact_fractions` so the report's time
+    split provably partitions the step total.
     """
     spans = _span_rows(trace_data)
     if not spans:
         return None
     self_us = _self_times_us(spans)
+    executes: dict = {}          # tid -> sorted (start, end) of calls
+    for e in spans:
+        if e["name"] == "engine.execute":
+            executes.setdefault(e.get("tid"), []).append(
+                (e["ts"], e["ts"] + e["dur"]))
+    for runs in executes.values():
+        runs.sort()
+
+    def nested(e) -> bool:
+        runs = executes.get(e.get("tid"), [])
+        i = bisect.bisect_right(runs, (e["ts"], math.inf)) - 1
+        return i >= 0 and e["ts"] + e["dur"] <= runs[i][1]
     step_us = queue_s = 0.0
     parts_us = {"assemble": 0.0, "execute": 0.0, "deliver": 0.0,
                 "step_self": 0.0}
@@ -277,6 +294,8 @@ def step_breakdown(trace_data: dict, pipeline: str) -> dict | None:
             queue_s += float(e["args"].get("queue_wait_s", 0.0))
         elif e["name"] == "engine.assemble":
             parts_us["assemble"] += float(e["dur"])
+            if nested(e):
+                parts_us["execute"] -= float(e["dur"])
         elif e["name"] == "engine.execute":
             parts_us["execute"] += float(e["dur"])
         elif e["name"] == "engine.deliver":

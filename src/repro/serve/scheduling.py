@@ -8,6 +8,7 @@ shape inline — migrating it here is an open refactor.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from collections import deque
 from typing import Any, Callable, Hashable, Iterable, Mapping
 
@@ -65,14 +66,15 @@ class BoundedFifo:
         return bool(self._q)
 
 
-def assemble_batch(queues: Mapping[Hashable, BoundedFifo], max_batch: int,
-                   age_of: Callable[[Any], float],
-                   compatible: Callable[[Any, Any], bool] | None = None,
-                   ) -> tuple[Hashable, list]:
-    """Oldest-head-first batch assembly across per-stream FIFOs.
+def peek_batch(queues: Mapping[Hashable, BoundedFifo], max_batch: int,
+               age_of: Callable[[Any], float],
+               compatible: Callable[[Any, Any], bool] | None = None,
+               ) -> tuple[Hashable, list]:
+    """Oldest-head-first batch choice across per-stream FIFOs, left in
+    place.
 
     Picks the stream whose head item is oldest (per ``age_of``, lower =
-    older), then pops up to ``max_batch`` items from that stream in FIFO
+    older), then takes up to ``max_batch`` items from its front in FIFO
     order, stopping early when ``compatible(first, item)`` says an item
     cannot share the batch (e.g. mismatched frame shapes must not be
     padded together). Returns (stream_key, items); (None, []) when idle.
@@ -82,11 +84,24 @@ def assemble_batch(queues: Mapping[Hashable, BoundedFifo], max_batch: int,
         return None, []
     key, q = min(live, key=lambda kq: age_of(kq[1].peek()))
     first = q.peek()
-    items = [q.pop()]
-    while q and len(items) < max_batch:
-        if compatible is not None and not compatible(first, q.peek()):
+    items = [first]
+    for item in itertools.islice(q, 1, None):
+        if len(items) == max_batch or (
+                compatible is not None and not compatible(first, item)):
             break
-        items.append(q.pop())
+        items.append(item)
+    return key, items
+
+
+def assemble_batch(queues: Mapping[Hashable, BoundedFifo], max_batch: int,
+                   age_of: Callable[[Any], float],
+                   compatible: Callable[[Any, Any], bool] | None = None,
+                   ) -> tuple[Hashable, list]:
+    """Pop the batch :func:`peek_batch` chooses. Returns (stream_key,
+    items); (None, []) when idle."""
+    key, items = peek_batch(queues, max_batch, age_of, compatible)
+    for _ in items:
+        queues[key].pop()
     return key, items
 
 

@@ -113,3 +113,111 @@ def test_engine_metrics_snapshot_shape():
     assert snap["mean_batch_fill"] == pytest.approx(1.0)
     assert snap["per_pipeline"] == {"unsharp-m": 4}
     assert snap["fps_execute"] > 0
+
+
+# -- the next full batch is copied and stacked while the current one runs
+
+def test_peek_batch_leaves_in_place_what_assemble_batch_pops():
+    from repro.serve.scheduling import BoundedFifo, assemble_batch, peek_batch
+    queues = {k: BoundedFifo(8) for k in "ab"}
+    for age, key, shape in [(1, "b", 1), (2, "a", 1), (3, "b", 1),
+                            (4, "b", 2), (5, "b", 2)]:
+        queues[key].push((age, shape))
+    kw = dict(age_of=lambda it: it[0],
+              compatible=lambda x, y: x[1] == y[1])
+    peeked = peek_batch(queues, 4, **kw)
+    assert peeked == ("b", [(1, 1), (3, 1)])    # stops at the shape change
+    assert [len(q) for q in queues.values()] == [1, 4]
+    assert assemble_batch(queues, 4, **kw) == peeked
+    assert [len(q) for q in queues.values()] == [1, 2]
+    assert peek_batch({}, 4, **kw) == (None, [])
+
+
+def _queued(n, start=0, pipeline="unsharp-m"):
+    return [_req(start + i, pipeline) for i in range(n)]
+
+
+def _one_batch_at_a_time(reqs, max_batch):
+    """The path without prefetch: each step finds only its own batch
+    queued."""
+    eng = FrameEngine(max_batch=max_batch, max_pending=16)
+    out = {}
+    for i in range(0, len(reqs), max_batch):
+        for r in reqs[i:i + max_batch]:
+            assert eng.submit(r)
+        out.update((c.rid, c.output) for c in eng.step())
+        assert eng.pending == 0
+    assert eng.metrics.batches_prefetched == 0
+    return out
+
+
+def test_prefetch_serves_each_batch_on_its_own_step_bitwise():
+    mb = 2
+    reqs = _queued(3 * mb)
+    eng = FrameEngine(max_batch=mb, max_pending=16)
+    for r in reqs:
+        assert eng.submit(r)
+    steps = []
+    while eng.pending:
+        steps.append(eng.step())
+        # the prepared batch counts as pending until its step serves it
+        assert eng.pending == len(reqs) - mb * len(steps)
+    assert [[c.rid for c in s] for s in steps] == [[0, 1], [2, 3], [4, 5]]
+    assert eng.metrics.batches_prefetched == 2
+    assert eng.metrics.batches == 3
+    assert eng.metrics.snapshot()["batches_prefetched"] == 2
+    alone = _one_batch_at_a_time(reqs, mb)
+    for c in (c for s in steps for c in s):
+        np.testing.assert_array_equal(np.asarray(c.output),
+                                      np.asarray(alone[c.rid]))
+
+
+@pytest.mark.parametrize("queued", [1, 2, 3], ids=["one", "full", "partial"])
+def test_no_prefetch_without_a_full_batch_behind(queued):
+    """One frame, one full batch, or a full batch with a partial one
+    behind it: no step takes work early."""
+    mb = 2
+    eng = FrameEngine(max_batch=mb, max_pending=16)
+    for r in _queued(queued):
+        assert eng.submit(r)
+    first = eng.step()
+    assert [c.rid for c in first] == list(range(min(queued, mb)))
+    assert eng.metrics.batches_prefetched == 0
+    assert eng.pending == queued - len(first)
+
+
+def test_prefetched_copy_fault_fails_only_its_batch_on_the_next_step():
+    mb = 2
+    reqs = _queued(3 * mb)
+    # a frame the host -> device copy refuses, in the second batch
+    reqs[3].frames = {"in": np.full((24, 32), "x", dtype=object)}
+    eng = FrameEngine(max_batch=mb, max_pending=16)
+    for r in reqs:
+        assert eng.submit(r)
+    first = eng.step()
+    assert [(type(c).__name__, c.rid) for c in first] == \
+        [("CompletedFrame", 0), ("CompletedFrame", 1)]
+    assert eng.metrics.batches_prefetched == 1 and eng.pending == 4
+    second = eng.step()
+    assert [(type(c).__name__, c.rid) for c in second] == \
+        [("FailedFrame", 2), ("FailedFrame", 3)]
+    third = eng.step()
+    assert [(type(c).__name__, c.rid) for c in third] == \
+        [("CompletedFrame", 4), ("CompletedFrame", 5)]
+    assert eng.metrics.frames_failed == 2
+    assert eng.metrics.frames_completed == 4 and eng.pending == 0
+
+
+def test_resilient_mode_never_prefetches():
+    from repro.resilience import ResilienceConfig
+    mb = 2
+    eng = FrameEngine(max_batch=mb, max_pending=16,
+                      resilience=ResilienceConfig())
+    for r in _queued(3 * mb):
+        assert eng.submit(r) is True
+    served = []
+    while eng.pending:
+        served.append([c.rid for c in eng.step()])
+        assert eng.pending == 3 * mb - mb * len(served)
+    assert served == [[0, 1], [2, 3], [4, 5]]
+    assert eng.metrics.batches_prefetched == 0

@@ -104,6 +104,30 @@ def test_frame_engine_batch_spans(global_trace):
                for e in h2d + _named("engine.deliver"))
 
 
+def test_frame_engine_prefetched_batch_spans(global_trace):
+    """Three full batches queued at once: each batch has one assembly,
+    one call and one delivery; the second and third are assembled while
+    the batch before them runs, inside its ``engine.execute``."""
+    _serve_frames(6, max_batch=2)
+    steps = _named("engine.step")
+    assembles = _named("engine.assemble")
+    executes = _named("engine.execute")
+    assert len(steps) == len(assembles) == len(executes) == 3
+    assert len(_named("engine.h2d")) == len(_named("engine.stack")) == 3
+    assert [e.attrs["frames"] for e in _named("engine.deliver")] == [2] * 3
+    assert [a.attrs["prefetched"] for a in assembles] == [False, True, True]
+    first, *later = assembles
+    assert first.parent == "engine.step" and first.depth == 1
+    assert _within(first, steps[0])
+    for a, ex in zip(later, executes):    # batch k + 1 under call k
+        assert a.parent == "engine.execute" and a.depth == 2
+        assert _within(a, ex)
+    for name in ("engine.h2d", "engine.stack"):
+        assert all(e.parent == "engine.assemble" and
+                   any(_within(e, a) for a in assembles)
+                   for e in _named(name))
+
+
 def test_video_engine_chunk_spans(global_trace):
     _serve_video(4, chunk=2)
     _check_tree([2, 2])
@@ -201,6 +225,37 @@ def test_step_breakdown_parts_partition_the_step():
     # self time: 100 - 30 - 40 - 12 and 50 - 30 (h2d, stack and the
     # call are grandchildren)
     assert b["step_self_s"] == pytest.approx(38e-6)
+    parts = ("assemble_s", "execute_s", "deliver_s", "step_self_s")
+    assert sum(b[k] for k in parts) == pytest.approx(b["step_s"])
+
+
+def test_step_breakdown_counts_a_prefetched_assembly_once():
+    """A batch assembled inside the previous call counts as assembly,
+    not also as execution."""
+    data = _chrome([
+        ("engine.step", 0, 100, 1),
+        ("engine.assemble", 5, 30, 1),
+        ("engine.h2d", 6, 20, 1),
+        ("engine.stack", 27, 7, 1),
+        ("engine.execute", 40, 40, 1),
+        ("executor.call", 41, 5, 1),
+        ("engine.assemble", 47, 25, 1),      # the next batch, prefetched
+        ("engine.h2d", 48, 15, 1),
+        ("engine.stack", 64, 7, 1),
+        ("engine.deliver", 82, 12, 1),
+        ("engine.step", 200, 60, 1),
+        ("engine.execute", 205, 35, 1),
+        ("executor.call", 206, 5, 1),
+        ("engine.deliver", 242, 10, 1),
+    ])
+    b = step_breakdown(data, "p")
+    assert b["n_steps"] == 2
+    assert b["step_s"] == pytest.approx(160e-6)
+    assert b["assemble_s"] == pytest.approx(55e-6)
+    assert b["execute_s"] == pytest.approx((40 - 25 + 35) * 1e-6)
+    assert b["deliver_s"] == pytest.approx(22e-6)
+    # 100 - 30 - 40 - 12 and 60 - 35 - 10
+    assert b["step_self_s"] == pytest.approx(33e-6)
     parts = ("assemble_s", "execute_s", "deliver_s", "step_self_s")
     assert sum(b[k] for k in parts) == pytest.approx(b["step_s"])
 
