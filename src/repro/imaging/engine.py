@@ -1,11 +1,10 @@
 """FrameEngine: slot-based continuous batching for stencil pipelines.
 
-The frame analogue of serve/engine.py: where the LM engine multiplexes
-token streams over KV-cache slots, the FrameEngine multiplexes frame
-requests over compiled-plan executors. The paper's accelerator compiles
-once and then streams frames; here the compiled artifact (plan + jitted
-Pallas kernel) lives in a PlanCache and the engine's job is purely
-scheduling:
+The FrameEngine multiplexes independent frame requests over
+compiled-plan executors. The paper's accelerator compiles once and then
+streams frames; here the compiled artifact (plan + jitted Pallas kernel)
+lives in a PlanCache and the engine's job is purely scheduling, on the
+serving core it shares with video.VideoEngine (imaging/serving.py):
 
   * **admission** — per-pipeline bounded FIFOs; a full queue refuses the
     request (backpressure to the caller) instead of growing without bound.
@@ -53,19 +52,13 @@ from typing import Mapping
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref
-from repro.kernels.stencil_pipeline import unstack
 from repro.obs import trace
-from repro.resilience import (AdmissionController, FailedFrame,
-                              FallbackLadder, Priority, RejectedFrame,
-                              ResilienceConfig, ShedFrame, overdue_s,
-                              pick_shed_victim, screen_frames,
-                              split_expired)
-from repro.serve.scheduling import (BoundedFifo, assemble_batch, pad_batch,
-                                    peek_batch)
+from repro.resilience import (FailedFrame, Priority, RejectedFrame,
+                              ResilienceConfig, screen_frames)
 
-from .metrics import EngineMetrics
 from .plan_cache import PlanCache
+from .serving import (BoundedFifo, Lane, ServingCore, assemble_batch,
+                      copy_and_stack, deliver, peek_batch)
 from .tiling import execute_tiled, rows_per_step_for_tile
 
 
@@ -105,7 +98,9 @@ class CompletedFrame:
     deadline_missed: bool = False
 
 
-class FrameEngine:
+class FrameEngine(ServingCore):
+    kind = "frame"
+
     def __init__(self, cache: PlanCache | None = None,
                  max_batch: int = 4, max_pending: int = 64,
                  tile_shape: tuple[int, int] = (128, 128),
@@ -114,49 +109,13 @@ class FrameEngine:
                  autotune: bool = False,
                  registry=None,
                  resilience: ResilienceConfig | None = None):
-        # ``registry``: a shared obs.MetricsRegistry for the serving
-        # telemetry plane; default = a private one per engine. A cache
-        # constructed here joins the same registry.
-        self.cache = cache if cache is not None else \
-            PlanCache(registry=registry,
-                      retry=resilience.retry if resilience else None)
+        super().__init__(cache, max_pending, rows_per_step, prefetch_depth,
+                         autotune, registry, resilience)
         self.max_batch = max_batch
-        self.max_pending = max_pending
         self.tile_shape = tile_shape
-        # row-group blocking factor for every executor this engine compiles;
-        # clamped per-batch so frames shorter than R still execute
-        self.rows_per_step = rows_per_step
-        # DMA/compute overlap depth for every executor this engine
-        # compiles (1 = synchronous BlockSpec streaming)
-        self.prefetch_depth = prefetch_depth
-        # opt-in: serve every pipeline with the cache's autotuned memory
-        # config (one design-space search per (pipeline, width), memoized)
-        self.autotune = autotune
-        self.resilience = resilience
         self._queues: dict[str, BoundedFifo] = {}
-        self.metrics = EngineMetrics(registry=registry,
-                                     prefix="frame_engine")
-        # live queue depth for the telemetry plane: spans only show work
-        # that *ran*; the collector needs the standing backlog as a gauge
-        self._pending_gauge = self.metrics.registry.gauge(
-            "frame_engine_pending_frames",
-            help="frames admitted but not yet served")
-        # shed outcomes produced at admission time (overload evictions)
-        # or by the expiry sweep; flushed into the next step()'s results
-        self._shed_outbox: list[ShedFrame] = []
         # strict mode: the next batch, made ready under the running one
         self._prepared: _Prepared | None = None
-        if resilience is not None:
-            self._admission = AdmissionController(
-                resilience.rate, resilience.burst, clock=trace.now)
-            self._ladder = FallbackLadder(
-                retry=resilience.retry,
-                failure_threshold=resilience.breaker_failures,
-                reset_after_s=resilience.breaker_reset_s,
-                on_retry=lambda a, d, e: self.metrics.observe_retry(d))
-        else:
-            self._admission = None
-            self._ladder = None
 
     # ------------------------------------------------------------ admission
     def submit(self, req: FrameRequest) -> bool | RejectedFrame:
@@ -182,14 +141,7 @@ class FrameEngine:
         if len({np.shape(f) for f in req.frames.values()}) != 1:
             raise ValueError(f"request {req.rid}: input frames must share "
                              f"one (H, W) shape")
-        req.submitted_at = time.perf_counter()
-        ok = self._queue_for(req.pipeline).push(req)
-        self.metrics.frames_offered += 1
-        if ok:
-            self.metrics.frames_submitted += 1
-        else:
-            self.metrics.frames_rejected += 1
-        return ok
+        return self._push(req, self._queue_for(req.pipeline))
 
     def _queue_for(self, pipeline: str) -> BoundedFifo:
         q = self._queues.get(pipeline)
@@ -197,7 +149,7 @@ class FrameEngine:
             q = self._queues[pipeline] = BoundedFifo(self.max_pending)
         return q
 
-    def _screen(self, req: FrameRequest) -> RejectedFrame | None:
+    def _route(self, req: FrameRequest) -> Lane | RejectedFrame:
         try:
             dag = self.cache.dag_for(req.pipeline)
         except KeyError as e:
@@ -212,101 +164,28 @@ class FrameEngine:
             reason, detail = defect
             return RejectedFrame(reason, pipeline=req.pipeline,
                                  detail=detail, rid=req.rid)
-        return None
+        return Lane(self._queue_for(req.pipeline), req.pipeline)
 
-    def _reject(self, rej: RejectedFrame) -> RejectedFrame:
-        self.metrics.frames_rejected += 1
-        with trace.span("resilience.reject", engine="frame",
-                        pipeline=rej.pipeline or "?", reason=rej.reason,
-                        retryable=rej.retryable):
-            pass
-        return rej
-
-    def _shed(self, req: FrameRequest, reason: str, now: float) -> None:
-        self.metrics.frames_shed += 1
-        od = overdue_s(req.deadline, now)
-        self._shed_outbox.append(ShedFrame(
-            reason=reason, pipeline=req.pipeline,
-            priority=int(req.priority), rid=req.rid, deadline=req.deadline,
-            overdue_s=od if od > float("-inf") else 0.0))
-        with trace.span("resilience.shed", engine="frame",
-                        pipeline=req.pipeline, reason=reason,
-                        priority=int(req.priority)):
-            pass
-
-    def _submit_resilient(self, req: FrameRequest) -> bool | RejectedFrame:
-        self.metrics.frames_offered += 1
-        rej = self._screen(req)
-        if rej is not None:
-            return self._reject(rej)
-        if not self._admission.allow(req.pipeline):
-            return self._reject(RejectedFrame(
-                "rate_limited", pipeline=req.pipeline, retryable=True,
-                rid=req.rid))
-        cfg = self.resilience
-        now = trace.now()
-        req.submitted_at = time.perf_counter()
-        dl = req.deadline_s if req.deadline_s is not None \
-            else cfg.default_deadline_s
-        req.deadline = (now + dl) if dl is not None else None
-        q = self._queue_for(req.pipeline)
-        if len(q) >= q.capacity and cfg.shed_on_overload:
-            victim = pick_shed_victim(
-                q, int(req.priority), now,
-                priority_of=lambda r: int(r.priority),
-                deadline_of=lambda r: r.deadline,
-                age_of=lambda r: r.submitted_at)
-            if victim is not None:
-                q.remove(victim)
-                self._shed(victim, "overload", now)
-        if not q.push(req):
-            return self._reject(RejectedFrame(
-                "saturated", pipeline=req.pipeline, retryable=True,
-                rid=req.rid))
-        self.metrics.frames_submitted += 1
-        return True
-
-    def _sweep_expired(self) -> None:
-        """Drop queued work whose deadline already passed — executing it
-        would burn capacity on a guaranteed SLA miss."""
-        now = trace.now()
-        for q in self._queues.values():
-            if not q:
-                continue
-            live, expired = split_expired(q.drain(), now,
-                                          lambda r: r.deadline)
-            for r in live:
-                q.push(r)
-            for r in expired:
-                self._shed(r, "deadline", now)
+    def _lanes(self):
+        return (Lane(q, name) for name, q in self._queues.items())
 
     @property
     def pending(self) -> int:
         prepared = len(self._prepared.reqs) if self._prepared else 0
-        return prepared + sum(len(q) for q in self._queues.values())
+        return prepared + super().pending
 
     # ------------------------------------------------------------ execution
     def _tiled(self, shape: tuple[int, int]) -> bool:
         th, tw = self.tile_shape
         return shape[0] > th or shape[1] > tw
 
-    def _assemble(self, name: str, reqs: list[FrameRequest], h: int, w: int,
+    def _assemble(self, name: str, reqs: list[FrameRequest],
                   prefetched: bool = False) -> dict:
         """Copy a batch's frames to the device and stack them, padded to
         ``max_batch``: the executor's inputs."""
-        feeds = self.cache.dag_for(name).input_stages()
-        with trace.span("engine.assemble", pipeline=name, xla=True,
-                        prefetched=prefetched):
-            with trace.span("engine.h2d", pipeline=name, xla=True,
-                            frames=len(reqs),
-                            bytes=len(reqs) * len(feeds) * h * w * 4):
-                copies = {n: [jnp.asarray(r.frames[n], jnp.float32)
-                              for r in reqs] for n in feeds}
-            with trace.span("engine.stack", pipeline=name, xla=True):
-                return {n: jnp.stack(pad_batch(
-                    c, self.max_batch,
-                    lambda: jnp.zeros((h, w), jnp.float32)))
-                    for n, c in copies.items()}
+        return copy_and_stack(name, self.cache.dag_for(name).input_stages(),
+                              [r.frames for r in reqs], reqs[0].shape,
+                              self.max_batch, prefetched=prefetched)
 
     def _prefetch(self) -> None:
         """Pop the next batch and copy and stack it now, while the batch
@@ -324,8 +203,7 @@ class FrameEngine:
         self._prepared = prep = _Prepared(name, reqs)
         self.metrics.batches_prefetched += 1
         try:
-            prep.inputs = self._assemble(name, reqs, *reqs[0].shape,
-                                         prefetched=True)
+            prep.inputs = self._assemble(name, reqs, prefetched=True)
         except Exception as e:  # noqa: BLE001 - the batch is popped:
             prep.error = e      # it fails on its own step, not this one
 
@@ -352,37 +230,13 @@ class FrameEngine:
                                      rows_per_step=rps, tune=tune,
                                      prefetch_depth=self.prefetch_depth)
         if inputs is None:
-            inputs = self._assemble(name, reqs, h, w)
+            inputs = self._assemble(name, reqs)
         with trace.span("engine.execute", pipeline=name, xla=True):
             batch_out = ex(inputs)
             if self.resilience is None:
                 self._prefetch()
             batch_out.block_until_ready()
-        with trace.span("engine.deliver", pipeline=name, xla=True,
-                        frames=len(reqs), programs=1):
-            # split every padded row, keep the live ones: one program
-            # per frame shape, whatever the fill
-            outs = list(unstack(batch_out)[:len(reqs)])
-        return outs, ex.vmem_bytes
-
-    def _run_reference(self, name: str,
-                       reqs: list[FrameRequest]) -> tuple[list, int]:
-        """The ladder's last rung: the pure-jnp oracle. Slow — no line
-        buffers, no fused kernel — but it has no plan, no executor, and
-        no cache to fail, so it bounds the blast radius of every
-        compiled-path fault at "degraded throughput"."""
-        dag = self.cache.dag_for(name)
-        with trace.span("engine.execute", pipeline=name, reference=True):
-            outs = [ref.stencil_pipeline_ref(
-                dag, {n: jnp.asarray(r.frames[n], jnp.float32)
-                      for n in dag.input_stages()}) for r in reqs]
-            for o in outs:
-                o.block_until_ready()
-        return outs, 0
-
-    @property
-    def _primary_rung(self) -> str:
-        return "tuned" if self.autotune else "default"
+        return deliver(name, batch_out, len(reqs)), ex.vmem_bytes
 
     def _execute(self, name: str, reqs: list[FrameRequest], h: int, w: int,
                  tiled: bool, rps: int, prepared: _Prepared | None = None
@@ -392,25 +246,14 @@ class FrameEngine:
         path directly (exceptions propagate to step()'s failure path),
         starting from ``prepared`` inputs when the last step made them,
         and prepares the next full batch while this one runs."""
-        if self.resilience is None:
-            if prepared is not None and prepared.error is not None:
-                raise prepared.error
-            outs, vmem = self._run_compiled(
-                name, reqs, h, w, tiled, rps, tune=self.autotune,
-                inputs=prepared.inputs if prepared else None)
-            return outs, vmem, self._primary_rung
-        rungs = []
-        if self.autotune:
-            rungs.append(("tuned",
-                          lambda: self._run_compiled(name, reqs, h, w,
-                                                     tiled, rps, True)))
-        rungs.append(("default",
-                      lambda: self._run_compiled(name, reqs, h, w,
-                                                 tiled, rps, False)))
-        if self.resilience.reference_fallback:
-            rungs.append(("reference",
-                          lambda: self._run_reference(name, reqs)))
-        (outs, vmem), rung = self._ladder.run(name, rungs)
+        if prepared is not None and prepared.error is not None:
+            raise prepared.error
+        inputs = prepared.inputs if prepared else None
+        (outs, vmem), rung = self._descend(
+            name,
+            lambda tune: self._run_compiled(name, reqs, h, w, tiled, rps,
+                                            tune, inputs),
+            lambda: (self._reference(name, reqs), 0))
         return outs, vmem, rung
 
     # ----------------------------------------------------------------- step
@@ -418,12 +261,7 @@ class FrameEngine:
         """Assemble and execute one batch; flushes pending shed/expiry
         outcomes first. Returns a mix of CompletedFrame, ShedFrame, and
         FailedFrame results ([] when idle)."""
-        results: list = []
-        if self.resilience is not None and self.resilience.shed_expired:
-            self._sweep_expired()
-        if self._shed_outbox:
-            results, self._shed_outbox = self._shed_outbox, []
-        self._pending_gauge.set(self.pending)
+        results = self._begin_step()
         prepared, self._prepared = self._prepared, None
         if prepared is not None:
             name, reqs = prepared.pipeline, prepared.reqs
@@ -434,11 +272,7 @@ class FrameEngine:
                 compatible=lambda a, b: a.shape == b.shape)
         if not reqs:
             return results
-        # queue wait: how long the batch's oldest frame sat admitted but
-        # unserved — the "where did the 40 ms go" term the executor time
-        # can never explain
-        queue_wait = time.perf_counter() - min(r.submitted_at for r in reqs)
-        self.metrics.observe_queue_wait(queue_wait)
+        queue_wait = self._queue_wait(reqs)
         h, w = reqs[0].shape
         th, tw = self.tile_shape
         tiled = self._tiled((h, w))
@@ -474,12 +308,8 @@ class FrameEngine:
             now_obs = trace.now()
             missed = 0
             for r, out in zip(reqs, outs):
-                lat = now - r.submitted_at
-                self.metrics.observe_latency(lat)
-                late = r.deadline is not None and now_obs > r.deadline
-                if late:
-                    missed += 1
-                    self.metrics.observe_deadline_miss(now_obs - r.deadline)
+                lat, late = self._served(r, now, now_obs)
+                missed += late
                 results.append(CompletedFrame(
                     rid=r.rid, pipeline=name, output=out, latency_s=lat,
                     rung=rung, deadline_missed=late))
@@ -495,18 +325,8 @@ class FrameEngine:
         pending = list(requests)
         results: dict = {}
         while pending or self.pending:
-            progressed = False
-            while pending:
-                r = self.submit(pending[0])
-                if r is True:
-                    pending.pop(0)
-                    progressed = True
-                elif isinstance(r, RejectedFrame) and not r.retryable:
-                    results[pending[0].rid] = r      # permanent: drop it
-                    pending.pop(0)
-                    progressed = True
-                else:
-                    break          # backpressure/rate limit: drain first
+            progressed, dropped = self._offer(pending)
+            results.update((req.rid, rej) for req, rej in dropped)
             for c in self.step():
                 progressed = True
                 if isinstance(c, CompletedFrame):
@@ -516,11 +336,3 @@ class FrameEngine:
             if not progressed:
                 time.sleep(0.001)  # rate-limit window: don't spin hot
         return results
-
-    def snapshot(self) -> dict:
-        """Engine + cache telemetry in one dict (the serving plane's
-        JSON view; the Prometheus view is metrics.registry)."""
-        snap = self.metrics.snapshot()
-        snap["pending"] = self.pending
-        snap["cache"] = self.cache.snapshot()
-        return snap

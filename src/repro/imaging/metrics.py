@@ -2,15 +2,15 @@
 
 Tracks the quantities the ROADMAP's serving story is judged on —
 throughput (frames/sec, wall and execute-only), request latency
-(submit -> completion, now with p50/p95/p99 from a bucketed histogram
-instead of the old mean/max-only RunningStat), queue wait, and the VMEM
-footprint of the resident compiled executors (the accelerator's "SRAM
-bill"). Counters live in an :class:`repro.obs.MetricsRegistry` behind
-the same attribute API as before (``metrics.frames_submitted += 1``
-still works — the attributes are properties over registry counters), so
-the engines keep their single-threaded plain-python increments while a
-shared registry turns N engines + caches into one scrapeable telemetry
-plane (``metrics.registry.to_prometheus_text()``).
+(submit -> completion, with p50/p95/p99 from a bucketed histogram),
+queue wait, and the VMEM footprint of the resident compiled executors
+(the accelerator's "SRAM bill"). Counters live in an
+:class:`repro.obs.MetricsRegistry` behind the same attribute API as
+before (``metrics.frames_submitted += 1`` still works — the attributes
+are properties over registry counters), so the engines keep their
+single-threaded plain-python increments while a shared registry turns N
+engines + caches into one scrapeable telemetry plane
+(``metrics.registry.to_prometheus_text()``).
 """
 from __future__ import annotations
 

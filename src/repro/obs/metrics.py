@@ -8,10 +8,10 @@ engines' needs:
     accumulate here too.
   * :class:`Gauge` — instantaneous or high-water values (resident VMEM).
   * :class:`Histogram` — bucketed distributions with p50/p95/p99
-    estimates, replacing the mean/max-only ``RunningStat`` view of
-    latency. Percentiles interpolate linearly inside the bucket that
-    crosses the rank, clamped to the observed min/max, so the estimate
-    is always within one bucket width of the exact value.
+    estimates of latency, not a mean and a max alone. Percentiles
+    interpolate linearly inside the bucket that crosses the rank,
+    clamped to the observed min/max, so the estimate is always within
+    one bucket width of the exact value.
 
 A :class:`MetricsRegistry` names and owns metrics (get-or-create, type
 checked) and renders two views: ``snapshot()`` (JSON-able dict, the
@@ -112,8 +112,8 @@ class Histogram:
 
     ``buckets`` are upper bounds (ascending); values above the last
     bound land in an implicit +Inf bucket. The exact extrema make the
-    percentile clamp tight and keep the old RunningStat snapshot keys
-    (count/mean/max/min) exact, so migrated engine metrics lose nothing.
+    percentile clamp tight and keep the snapshot keys count/mean/max/min
+    exact.
     """
     __slots__ = ("name", "help", "buckets", "counts", "count", "total",
                  "min", "max", "_lock")

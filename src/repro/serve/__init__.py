@@ -1,16 +1,12 @@
-"""LM serving engine + scheduling primitives shared with imaging/.
+"""LM serving engine and KV-cache planner.
 
-The engine (and its model-stack imports) loads lazily: the frame-serving
-subsystem imports ``repro.serve.scheduling`` and must not pay for — or
-inherit the failure surface of — the transformer stack it never uses.
+Both load lazily, so importing the package does not pay for — or
+inherit the failure surface of — the transformer stack.
 """
-from .scheduling import BoundedFifo, RunningStat, assemble_batch
-
 _ENGINE = {"Completed", "Engine", "Request"}
 _PLANNER = {"KVPlan", "plan_kv"}
 
-__all__ = sorted({"BoundedFifo", "RunningStat", "assemble_batch"}
-                 | _ENGINE | _PLANNER)
+__all__ = sorted(_ENGINE | _PLANNER)
 
 
 def __getattr__(name):

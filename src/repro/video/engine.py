@@ -1,10 +1,12 @@
 """VideoEngine: multiplexed streaming of temporal pipelines.
 
-The video analogue of imaging.FrameEngine — but where the frame engine
-treats every request as independent, a video stream is *stateful*: each
-temporal producer's last d-1 frames live in a device-resident frame ring
-that must follow the stream, frame order matters, and two streams of the
-same pipeline must never see each other's history. The engine therefore
+VideoEngine runs on the serving core it shares with imaging.FrameEngine
+(imaging/serving.py: admission, resilience, batch copy and delivery) —
+but where the frame engine treats every request as independent, a video
+stream is *stateful*: each temporal producer's last d-1 frames live in a
+device-resident frame ring that must follow the stream, frame order
+matters, and two streams of the same pipeline must never see each
+other's history. The engine therefore
 splits the world in two:
 
   * **compiled artifacts are shared** — one VideoExecutor per (pipeline,
@@ -58,18 +60,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.algorithms import execute_reference_video
-from repro.imaging.metrics import EngineMetrics
 from repro.imaging.plan_cache import PlanCache
+from repro.imaging.serving import (BoundedFifo, Lane, ServingCore,
+                                   assemble_batch, copy_and_stack,
+                                   copy_frame, deliver)
 from repro.imaging.tiling import rows_per_step_for_tile
-from repro.kernels import ref
-from repro.kernels.stencil_pipeline import init_frame_state, unstack
+from repro.kernels.stencil_pipeline import init_frame_state
 from repro.obs import trace
-from repro.resilience import (AdmissionController, CancelledFrame,
-                              FailedFrame, FallbackLadder, LadderExhausted,
-                              Priority, RejectedFrame, ResilienceConfig,
-                              ShedFrame, overdue_s, pick_shed_victim,
-                              screen_frames, split_expired)
-from repro.serve.scheduling import BoundedFifo, assemble_batch
+from repro.resilience import (CancelledFrame, FailedFrame, Priority,
+                              RejectedFrame, ResilienceConfig, screen_frames)
 
 
 @dataclasses.dataclass
@@ -121,7 +120,9 @@ class VideoSession:
     first_warm_at: float | None = None
 
 
-class VideoEngine:
+class VideoEngine(ServingCore):
+    kind = "video"
+
     def __init__(self, cache: PlanCache | None = None,
                  chunk: int = 4, max_pending: int = 64,
                  rows_per_step: int = 8,
@@ -129,44 +130,14 @@ class VideoEngine:
                  autotune: bool = False,
                  registry=None,
                  resilience: ResilienceConfig | None = None):
-        # ``registry``: a shared obs.MetricsRegistry for the serving
-        # telemetry plane; default = a private one per engine
-        self.cache = cache if cache is not None else \
-            PlanCache(registry=registry,
-                      retry=resilience.retry if resilience else None)
+        super().__init__(cache, max_pending, rows_per_step, prefetch_depth,
+                         autotune, registry, resilience)
         self.chunk = chunk
-        self.max_pending = max_pending
-        self.rows_per_step = rows_per_step
-        # DMA/compute overlap depth for every streaming executor this
-        # engine compiles (1 = synchronous BlockSpec streaming)
-        self.prefetch_depth = prefetch_depth
-        # opt-in: stream through the cache's autotuned memory config (one
-        # memoized design-space search per (pipeline, width))
-        self.autotune = autotune
-        self.resilience = resilience
         self._sessions: dict[int, VideoSession] = {}
         self._ids = itertools.count()
-        self.metrics = EngineMetrics(registry=registry,
-                                     prefix="video_engine")
         self.warmup_latency_s = self.metrics.registry.histogram(
             "video_engine_warmup_latency_s",
             help="stream open -> first fully-warm output, seconds")
-        # live backlog gauge for the telemetry plane (see FrameEngine)
-        self._pending_gauge = self.metrics.registry.gauge(
-            "video_engine_pending_frames",
-            help="frames admitted but not yet served across streams")
-        self._shed_outbox: list[ShedFrame] = []
-        if resilience is not None:
-            self._admission = AdmissionController(
-                resilience.rate, resilience.burst, clock=trace.now)
-            self._ladder = FallbackLadder(
-                retry=resilience.retry,
-                failure_threshold=resilience.breaker_failures,
-                reset_after_s=resilience.breaker_reset_s,
-                on_retry=lambda a, d, e: self.metrics.observe_retry(d))
-        else:
-            self._admission = None
-            self._ladder = None
 
     # ------------------------------------------------------------- streams
     def open_stream(self, pipeline: str, h: int, w: int,
@@ -218,125 +189,52 @@ class VideoEngine:
         del self._sessions[sid]
         return cancelled
 
-    @property
-    def pending(self) -> int:
-        return sum(len(s.queue) for s in self._sessions.values())
-
     # ----------------------------------------------------------- admission
     def submit(self, frame: VideoFrame) -> bool | RejectedFrame:
         """Enqueue one frame; False = stream saturated (backpressure).
         Legacy strict mode raises on malformed frames here, at
         admission; resilient mode returns a falsy RejectedFrame for
         every refusal instead."""
-        if self.resilience is not None:
-            return self._submit_resilient(frame)
         s = self._sessions.get(frame.stream)
-        if s is None:
-            raise KeyError(f"unknown stream {frame.stream}")
-        if not s.inputs <= set(frame.frames):
-            raise ValueError(f"stream {s.sid}: pipeline {s.pipeline!r} "
-                             f"needs inputs {sorted(s.inputs)}, got "
-                             f"{sorted(frame.frames)}")
-        for n in s.inputs:
-            if tuple(np.shape(frame.frames[n])) != (s.h, s.w):
-                raise ValueError(
-                    f"stream {s.sid}: frame shape "
-                    f"{tuple(np.shape(frame.frames[n]))} != ({s.h}, {s.w})")
-        frame.submitted_at = time.perf_counter()
-        self.metrics.frames_offered += 1
-        ok = s.queue.push(frame)
-        if ok:
-            s.submitted += 1
-            self.metrics.frames_submitted += 1
+        if self.resilience is not None:
+            ok = self._submit_resilient(frame)
         else:
-            self.metrics.frames_rejected += 1
+            if s is None:
+                raise KeyError(f"unknown stream {frame.stream}")
+            if not s.inputs <= set(frame.frames):
+                raise ValueError(f"stream {s.sid}: pipeline {s.pipeline!r} "
+                                 f"needs inputs {sorted(s.inputs)}, got "
+                                 f"{sorted(frame.frames)}")
+            for n in s.inputs:
+                if tuple(np.shape(frame.frames[n])) != (s.h, s.w):
+                    raise ValueError(
+                        f"stream {s.sid}: frame shape "
+                        f"{tuple(np.shape(frame.frames[n]))} != "
+                        f"({s.h}, {s.w})")
+            ok = self._push(frame, s.queue)
+        if ok is True:
+            s.submitted += 1
         return ok
 
-    def _reject(self, rej: RejectedFrame) -> RejectedFrame:
-        self.metrics.frames_rejected += 1
-        with trace.span("resilience.reject", engine="video",
-                        pipeline=rej.pipeline or "?", reason=rej.reason,
-                        retryable=rej.retryable):
-            pass
-        return rej
-
-    def _shed(self, frame: VideoFrame, reason: str, now: float,
-              s: VideoSession) -> None:
-        self.metrics.frames_shed += 1
-        od = overdue_s(frame.deadline, now)
-        self._shed_outbox.append(ShedFrame(
-            reason=reason, pipeline=s.pipeline,
-            priority=int(frame.priority), stream=s.sid, rid=frame.rid,
-            deadline=frame.deadline,
-            overdue_s=od if od > float("-inf") else 0.0))
-        with trace.span("resilience.shed", engine="video",
-                        pipeline=s.pipeline, stream=s.sid, reason=reason,
-                        priority=int(frame.priority)):
-            pass
-
-    def _submit_resilient(self, frame: VideoFrame) -> bool | RejectedFrame:
-        self.metrics.frames_offered += 1
+    def _route(self, frame: VideoFrame) -> Lane | RejectedFrame:
         s = self._sessions.get(frame.stream)
         if s is None:
-            return self._reject(RejectedFrame(
-                "unknown_stream", stream=frame.stream,
-                detail=f"no open stream {frame.stream}"))
+            return RejectedFrame("unknown_stream", stream=frame.stream,
+                                 detail=f"no open stream {frame.stream}")
         defect = screen_frames(frame.frames, s.inputs,
                                expect_shape=(s.h, s.w))
         if defect is not None:
             reason, detail = defect
-            return self._reject(RejectedFrame(
-                reason, pipeline=s.pipeline, detail=detail,
-                stream=s.sid))
-        if not self._admission.allow(s.sid):
-            return self._reject(RejectedFrame(
-                "rate_limited", pipeline=s.pipeline, retryable=True,
-                stream=s.sid))
-        cfg = self.resilience
-        now = trace.now()
-        frame.submitted_at = time.perf_counter()
+            return RejectedFrame(reason, pipeline=s.pipeline, detail=detail,
+                                 stream=s.sid)
         frame.priority = int(s.priority)
-        dl = frame.deadline_s if frame.deadline_s is not None \
-            else cfg.default_deadline_s
-        frame.deadline = (now + dl) if dl is not None else None
-        q = s.queue
-        if len(q) >= q.capacity and cfg.shed_on_overload:
-            # within one stream every frame shares the session priority,
-            # so eviction here only ever claims an expired resident —
-            # classic live-video frame dropping, never reordering
-            victim = pick_shed_victim(
-                q, int(frame.priority), now,
-                priority_of=lambda f: int(f.priority),
-                deadline_of=lambda f: f.deadline,
-                age_of=lambda f: f.submitted_at)
-            if victim is not None:
-                q.remove(victim)
-                self._shed(victim, "overload", now, s)
-        if not q.push(frame):
-            return self._reject(RejectedFrame(
-                "saturated", pipeline=s.pipeline, retryable=True,
-                stream=s.sid))
-        s.submitted += 1
-        self.metrics.frames_submitted += 1
-        return True
+        return Lane(s.queue, s.pipeline, s.sid)
 
-    def _sweep_expired(self) -> None:
-        now = trace.now()
-        for s in self._sessions.values():
-            if not s.queue:
-                continue
-            live, expired = split_expired(s.queue.drain(), now,
-                                          lambda f: f.deadline)
-            for f in live:
-                s.queue.push(f)
-            for f in expired:
-                self._shed(f, "deadline", now, s)
+    def _lanes(self):
+        return (Lane(s.queue, s.pipeline, s.sid)
+                for s in self._sessions.values())
 
     # ------------------------------------------------------------ execution
-    @property
-    def _primary_rung(self) -> str:
-        return "tuned" if self.autotune else "default"
-
     def _run_chunk(self, s: VideoSession, frames: list[VideoFrame],
                    n: int, rps: int, tune: bool):
         """Full-chunk executor call. Returns (outs, new_state, vmem);
@@ -346,20 +244,12 @@ class VideoEngine:
                                            rows_per_step=rps,
                                            tune=tune,
                                            prefetch_depth=self.prefetch_depth)
-        with trace.span("engine.assemble", pipeline=s.pipeline, xla=True):
-            with trace.span("engine.h2d", pipeline=s.pipeline, xla=True,
-                            frames=n, bytes=n * len(s.inputs) * s.h * s.w * 4):
-                copies = {name: [jnp.asarray(f.frames[name], jnp.float32)
-                                 for f in frames] for name in s.inputs}
-            with trace.span("engine.stack", pipeline=s.pipeline, xla=True):
-                ins = {name: jnp.stack(c) for name, c in copies.items()}
-            del copies      # the per-frame copies die once stacked
+        ins = copy_and_stack(s.pipeline, s.inputs, [f.frames for f in frames],
+                             (s.h, s.w), n)
         with trace.span("engine.execute", pipeline=s.pipeline, xla=True):
             out, new_state = ex(ins, s.state)
             out.block_until_ready()
-        with trace.span("engine.deliver", pipeline=s.pipeline, xla=True,
-                        frames=n, programs=1):
-            outs = list(unstack(out))
+        outs = deliver(s.pipeline, out, n)
         return outs, new_state, ex.vmem_bytes + ex.frame_state_bytes
 
     def _run_frame(self, s: VideoSession, f: VideoFrame,
@@ -369,10 +259,7 @@ class VideoEngine:
                                            rows_per_step=rps,
                                            tune=tune,
                                            prefetch_depth=self.prefetch_depth)
-        with trace.span("engine.h2d", pipeline=s.pipeline, xla=True,
-                        frames=1, bytes=len(s.inputs) * s.h * s.w * 4):
-            images = {name: jnp.asarray(f.frames[name], jnp.float32)
-                      for name in s.inputs}
+        images = copy_frame(s.pipeline, s.inputs, f.frames, (s.h, s.w))
         with trace.span("engine.execute", pipeline=s.pipeline, xla=True):
             out, new_state = ex(images, s.state)
             out.block_until_ready()
@@ -387,13 +274,10 @@ class VideoEngine:
         internal temporal producers recompute within reference accuracy.
         """
         dag = self.cache.dag_for(s.pipeline)
+        if not dag.is_temporal():
+            return self._reference(s.pipeline, frames), dict(s.state), 0
         with trace.span("engine.execute", pipeline=s.pipeline,
                         reference=True):
-            if not dag.is_temporal():
-                outs = [ref.stencil_pipeline_ref(
-                    dag, {k: jnp.asarray(f.frames[k], jnp.float32)
-                          for k in s.inputs}) for f in frames]
-                return outs, dict(s.state), 0
             videos = {}
             for k in s.inputs:
                 seq = [jnp.asarray(x, jnp.float32) for x in s.history[k]]
@@ -433,77 +317,42 @@ class VideoEngine:
             for f in frames:
                 s.history[k].append(np.asarray(f.frames[k], np.float32))
 
-    def _rungs(self, s: VideoSession, frames: list[VideoFrame],
-               make_compiled):
-        rungs = []
-        if self.autotune:
-            rungs.append(("tuned", make_compiled(True)))
-        rungs.append(("default", make_compiled(False)))
-        if self.resilience.reference_fallback:
-            rungs.append(("reference",
-                          lambda: self._reference_serve(s, frames)))
-        return rungs
-
     def _execute_stream(self, s: VideoSession, frames: list[VideoFrame]):
         """Serve ``frames`` (in order) against the session. Returns
         (served, failed, vmem, rps) with served = [(frame, out, rung)]
         and failed = [(frame, error_str)]; session state advances only
-        over the served prefix/frames."""
+        over the served prefix/frames. An executor exception becomes
+        structured failures instead of escaping with the frames already
+        popped."""
         n = len(frames)
         dag = self.cache.dag_for(s.pipeline)
         rps = rows_per_step_for_tile(s.h, self.rows_per_step)
         chunkable = all(p in s.inputs for p in dag.temporal_depths())
         use_chunk = n == self.chunk and n > 1 and chunkable
-        served: list = []
-        failed: list = []
-        vmem = 0
-        if self.resilience is None:
-            # strict mode: primary path only, but an executor exception
-            # becomes structured failures for the unserved frames
-            # instead of escaping with the batch already popped
-            try:
-                if use_chunk:
-                    outs, new_state, vmem = self._run_chunk(
-                        s, frames, n, rps, self.autotune)
-                    s.state = new_state
-                    served = [(f, o, self._primary_rung)
-                              for f, o in zip(frames, outs)]
-                else:
-                    for f in frames:
-                        outs, new_state, vm = self._run_frame(
-                            s, f, rps, self.autotune)
-                        s.state = new_state
-                        vmem = max(vmem, vm)
-                        served.append((f, outs[0], self._primary_rung))
-            except Exception as e:  # noqa: BLE001 - structured failure
-                err = repr(e)
-                failed = [(f, err) for f in frames[len(served):]]
-            return served, failed, vmem, rps
-
         if use_chunk:
-            rungs = self._rungs(
-                s, frames,
-                lambda tune: (lambda: self._run_chunk(s, frames, n, rps,
-                                                      tune)))
             try:
-                (outs, new_state, vmem), rung = self._ladder.run(
-                    (s.pipeline, "chunk"), rungs)
-            except LadderExhausted as e:
+                (outs, new_state, vmem), rung = self._descend(
+                    (s.pipeline, "chunk"),
+                    lambda tune: self._run_chunk(s, frames, n, rps, tune),
+                    lambda: self._reference_serve(s, frames))
+            except Exception as e:  # noqa: BLE001 - structured failure
                 return [], [(f, repr(e)) for f in frames], 0, rps
             s.state = new_state
             self._remember(s, frames)
-            served = [(f, o, rung) for f, o in zip(frames, outs)]
-            return served, failed, vmem, rps
-
-        for f in frames:
-            rungs = self._rungs(
-                s, [f],
-                lambda tune, f=f: (lambda: self._run_frame(s, f, rps,
-                                                           tune)))
+            return [(f, o, rung) for f, o in zip(frames, outs)], [], vmem, rps
+        served: list = []
+        failed: list = []
+        vmem = 0
+        for i, f in enumerate(frames):
             try:
-                (outs, new_state, vm), rung = self._ladder.run(
-                    (s.pipeline, "frame"), rungs)
-            except LadderExhausted as e:
+                (outs, new_state, vm), rung = self._descend(
+                    (s.pipeline, "frame"),
+                    lambda tune: self._run_frame(s, f, rps, tune),
+                    lambda: self._reference_serve(s, [f]))
+            except Exception as e:  # noqa: BLE001 - structured failure
+                if self.resilience is None:     # strict: the rest fails too
+                    failed += [(g, repr(e)) for g in frames[i:]]
+                    break
                 failed.append((f, repr(e)))
                 continue    # state untouched: the stream skips this frame
             s.state = new_state
@@ -518,12 +367,7 @@ class VideoEngine:
         pending shed outcomes first. Returns a mix of
         CompletedVideoFrame, ShedFrame, and FailedFrame ([] when idle).
         """
-        results: list = []
-        if self.resilience is not None and self.resilience.shed_expired:
-            self._sweep_expired()
-        if self._shed_outbox:
-            results, self._shed_outbox = self._shed_outbox, []
-        self._pending_gauge.set(self.pending)
+        results = self._begin_step()
         live = {sid: s.queue for sid, s in self._sessions.items()}
         sid, frames = assemble_batch(live, self.chunk,
                                      age_of=lambda f: f.submitted_at)
@@ -531,9 +375,7 @@ class VideoEngine:
             return results
         s = self._sessions[sid]
         n = len(frames)
-        queue_wait = (time.perf_counter()
-                      - min(f.submitted_at for f in frames))
-        self.metrics.observe_queue_wait(queue_wait)
+        queue_wait = self._queue_wait(frames)
         with trace.span("engine.step", xla=True, engine="video",
                         pipeline=s.pipeline, stream=sid, n_frames=n,
                         queue_wait_s=queue_wait) as sp:
@@ -557,11 +399,7 @@ class VideoEngine:
             if warm and s.first_warm_at is None:
                 s.first_warm_at = now
                 self.warmup_latency_s.observe(now - s.opened_at)
-            lat = now - f.submitted_at
-            self.metrics.observe_latency(lat)
-            late = f.deadline is not None and now_obs > f.deadline
-            if late:
-                self.metrics.observe_deadline_miss(now_obs - f.deadline)
+            lat, late = self._served(f, now, now_obs)
             results.append(CompletedVideoFrame(
                 stream=sid, pipeline=s.pipeline, index=idx, output=out,
                 warm=warm, latency_s=lat, rung=rung, deadline_missed=late,
@@ -593,16 +431,8 @@ class VideoEngine:
         while any(pending.values()) or any(queued(sid) for sid in streams):
             progressed = False
             for sid, frames in pending.items():
-                while frames:
-                    r = self.submit(VideoFrame(sid, frames[0]))
-                    if r is True:
-                        frames.pop(0)
-                        progressed = True
-                    elif isinstance(r, RejectedFrame) and not r.retryable:
-                        frames.pop(0)       # permanent: skip the frame
-                        progressed = True
-                    else:
-                        break
+                progressed |= self._offer(
+                    frames, lambda f, sid=sid: VideoFrame(sid, f))[0]
             for c in self.step():
                 progressed = True
                 if isinstance(c, CompletedVideoFrame):
@@ -612,9 +442,7 @@ class VideoEngine:
         return results
 
     def snapshot(self) -> dict:
-        snap = self.metrics.snapshot()
+        snap = super().snapshot()
         snap["warmup_latency"] = self.warmup_latency_s.snapshot()
         snap["open_streams"] = len(self._sessions)
-        snap["pending"] = self.pending
-        snap["cache"] = self.cache.snapshot()
         return snap

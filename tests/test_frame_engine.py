@@ -118,7 +118,7 @@ def test_engine_metrics_snapshot_shape():
 # -- the next full batch is copied and stacked while the current one runs
 
 def test_peek_batch_leaves_in_place_what_assemble_batch_pops():
-    from repro.serve.scheduling import BoundedFifo, assemble_batch, peek_batch
+    from repro.imaging.serving import BoundedFifo, assemble_batch, peek_batch
     queues = {k: BoundedFifo(8) for k in "ab"}
     for age, key, shape in [(1, "b", 1), (2, "a", 1), (3, "b", 1),
                             (4, "b", 2), (5, "b", 2)]:

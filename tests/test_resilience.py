@@ -252,22 +252,53 @@ def test_resilient_submit_quarantines_instead_of_raising():
     assert rec["balanced"] and rec["offered"] == 6 and rec["rejected"] == 5
 
 
-def test_resilient_rate_limit_is_retryable():
-    eng = FrameEngine(max_pending=64,
-                      resilience=_cfg(rate=1000.0, burst=2.0))
-    verdicts = [eng.submit(_req(i)) for i in range(4)]
+def _frame_engine(cfg, **kw):
+    """A resilient FrameEngine and a way to offer it request ``rid``."""
+    eng = FrameEngine(resilience=cfg, **kw)
+    return eng, lambda rid, **r: eng.submit(_req(rid, **r))
+
+
+def _video_engine(cfg, max_batch=None, **kw):
+    """A resilient VideoEngine with one open stream, offered the same
+    way; a step serves up to ``chunk`` frames, not ``max_batch``."""
+    eng = VideoEngine(resilience=cfg, **kw)
+    sid = eng.open_stream("tmotion-t", 16, 24)
+    return eng, lambda rid, **r: eng.submit(
+        VideoFrame(sid, {"in": _frame()}, rid=rid, **r))
+
+
+ENGINES = pytest.mark.parametrize("make", [_frame_engine, _video_engine],
+                                  ids=["frame", "video"])
+
+
+@ENGINES
+def test_resilient_rate_limit_is_retryable(make):
+    eng, submit = make(_cfg(rate=1000.0, burst=2.0), max_pending=64)
+    verdicts = [submit(i) for i in range(4)]
     assert verdicts[:2] == [True, True]
     rejected = [v for v in verdicts if isinstance(v, RejectedFrame)]
     assert rejected and all(v.reason == "rate_limited" and v.retryable
                             for v in rejected)
 
 
-def test_overload_sheds_lowest_priority_first():
-    eng = FrameEngine(max_batch=2, max_pending=2, resilience=_cfg())
-    assert eng.submit(_req(0, priority=Priority.LOW)) is True
-    assert eng.submit(_req(1, priority=Priority.HIGH)) is True
-    # queue full; a NORMAL newcomer displaces the LOW resident
-    assert eng.submit(_req(2, priority=Priority.NORMAL)) is True
+# what the first resident, the second and a newcomer ask: FrameEngine
+# evicts the lowest priority class; within one stream every frame shares
+# the session's priority, so VideoEngine evicts only an expired resident
+@pytest.mark.parametrize("make,asks", [
+    (_frame_engine, [dict(priority=Priority.LOW),
+                     dict(priority=Priority.HIGH),
+                     dict(priority=Priority.NORMAL)]),
+    (_video_engine, [dict(deadline_s=-1.0), {}, {}]),
+], ids=["frame", "video"])
+def test_overload_sheds_lowest_priority_first(make, asks):
+    eng, submit = make(_cfg(), max_batch=2, max_pending=2)
+    assert submit(0, **asks[0]) is True
+    assert submit(1, **asks[1]) is True
+    # queue full; the newcomer displaces resident 0
+    assert submit(2, **asks[2]) is True
+    # ...but not an in-SLA resident of its own class
+    refused = submit(3, **asks[2])
+    assert refused.reason == "saturated" and refused.retryable
     outcomes = []
     while eng.pending or not outcomes:
         outcomes += eng.step()
@@ -279,10 +310,11 @@ def test_overload_sheds_lowest_priority_first():
     assert eng.metrics.reconcile()["balanced"]
 
 
-def test_expired_deadlines_swept_before_execution():
-    eng = FrameEngine(resilience=_cfg())
-    assert eng.submit(_req(0, deadline_s=-1.0)) is True   # born expired
-    assert eng.submit(_req(1)) is True
+@ENGINES
+def test_expired_deadlines_swept_before_execution(make):
+    eng, submit = make(_cfg())
+    assert submit(0, deadline_s=-1.0) is True            # born expired
+    assert submit(1) is True
     outcomes = []
     while eng.pending or not outcomes:
         outcomes += eng.step()

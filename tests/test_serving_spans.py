@@ -146,6 +146,36 @@ def test_video_single_frame_copies_before_the_call(global_trace):
     assert not _named("engine.stack") and not _named("engine.deliver")
 
 
+def test_both_engines_trace_one_transfer_path(global_trace):
+    """The same two frames, as one FrameEngine batch and as one
+    VideoEngine chunk, open their copy, stack and delivery spans with
+    the same attributes."""
+    frames = _frames(2)
+
+    def serve_video():
+        eng = VideoEngine(chunk=2)
+        sid = eng.open_stream("unsharp-m", H, W)
+        eng.run({sid: [{"in": f} for f in frames]})
+
+    seen = {}
+    for kind, serve in [
+            ("frame", lambda: FrameEngine(max_batch=2).run(
+                [FrameRequest(rid=i, pipeline="unsharp-m",
+                              frames={"in": f})
+                 for i, f in enumerate(frames)])),
+            ("video", serve_video)]:
+        trace.clear()
+        serve()
+        seen[kind] = {name: [e.attrs for e in _named(name)]
+                      for name in ("engine.h2d", "engine.stack",
+                                   "engine.deliver")}
+    assert seen["frame"] == seen["video"]
+    (h2d,), (deliver,) = seen["frame"]["engine.h2d"], \
+        seen["frame"]["engine.deliver"]
+    assert h2d["bytes"] == 2 * 1 * H * W * 4     # frames x feeds x h x w
+    assert (deliver["frames"], deliver["programs"]) == (2, 1)
+
+
 def test_serving_spans_silent_when_tracing_disabled():
     assert not trace.enabled()
     trace.clear()
